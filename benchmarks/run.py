@@ -9,7 +9,8 @@ CNN/LSTM on synthetic data) — trends and orderings are the reproduction
 target; see EXPERIMENTS.md.
 """
 import argparse
-import subprocess
+import importlib.util
+import os
 import sys
 import time
 import traceback
@@ -28,6 +29,18 @@ from benchmarks import (
     table3_attack_success,
     table4_contribution_rates,
 )
+from repro.compile_cache import enable_compile_cache
+
+
+def _obs_report(*argv: str) -> int:
+    """Run ``scripts/obs_report.py`` in this process: a child process could
+    not reach a chip this process already holds."""
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__))), "scripts", "obs_report.py")
+    spec = importlib.util.spec_from_file_location("obs_report", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.main(list(argv))
 
 
 def main() -> None:
@@ -35,6 +48,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true", help="reduced iteration counts")
     ap.add_argument("--only", help="run a single bench by prefix")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # defaults sized for the CPU container (~45 min total); the paper-scale
     # sweep is the same code with larger counts (EXPERIMENTS.md notes scale)
@@ -95,8 +109,7 @@ def main() -> None:
         *([("serve_load", lambda: serve_load.run_serve_load())]
           if args.only else []),
         # demo: write a Perfetto trace + metrics JSONL from a small sim
-        *([("obs_report", lambda: subprocess.check_call(
-            [sys.executable, "scripts/obs_report.py", "--iterations", "10"]))]
+        *([("obs_report", lambda: _obs_report("--iterations", "10"))]
           if args.only else []),
         ("gossip", lambda: (
             gossip_propagation.run_sweep(iters_mid),

@@ -38,7 +38,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
-def main() -> int:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--nodes", type=int, default=8)
     ap.add_argument("--iterations", type=int, default=12)
@@ -51,7 +51,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out-prefix",
                     default=os.path.join("bench_artifacts", "obs_sample"))
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from repro.fl.experiments import default_dagfl_config, make_cnn_setup
     from repro.fl.systems import SimConfig, run_dagfl_gossip

@@ -19,8 +19,8 @@ override for tests). Members:
 * ``flash_attention`` / ``wkv`` — the model-side attention/recurrence
   kernels served from the gossiped bank.
 
-``repro.kernels.ops`` re-exports jit'd wrappers with container-aware
-``interpret`` defaults.
+``repro.kernels.ops`` re-exports jit'd wrappers; ``repro.kernels.dispatch``
+holds the one rule that picks kernel or oracle, compiled or interpreted.
 """
 from repro.kernels import ops, ref
 from repro.kernels.delta_codec import DeltaCodec

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import ref
+from repro.kernels.dispatch import interpret_mode, pick_impl
 
 BLOCK_S = 128   # digest slot-block per grid step
 
@@ -64,7 +65,7 @@ def chunk_dedup_pallas(
     have: jnp.ndarray,      # (R, S, C) bool
     digest: jnp.ndarray,    # (S, C) f32
     block_s: int = BLOCK_S,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     """(R, S, C) bool effective availability — the Pallas reduction.
 
@@ -91,7 +92,7 @@ def chunk_dedup_pallas(
         ],
         out_specs=pl.BlockSpec((1, bs, c), lambda i, sb: (i, sb, 0)),
         out_shape=jax.ShapeDtypeStruct((r, s + pad, c), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(hv, dig, dig)
     # physical presence short-circuits the digest match (NaN digests — a
     # payload that trained to NaN — compare unequal even to themselves;
@@ -103,18 +104,11 @@ def chunk_dedup(have, digest, impl: str = None, block_s: int = BLOCK_S,
                 interpret: bool = None) -> jnp.ndarray:
     """Content-addressed availability with backend dispatch.
 
-    ``impl``: "pallas" forces the kernel (interpreted off-TPU), "lax" the
-    pure-lax oracle; None picks pallas on TPU, lax elsewhere — the same
-    rule as ``gossip_merge.gossip_winner``.
+    ``impl``: "pallas" forces the kernel, "lax" the pure-lax oracle; None
+    follows ``repro.kernels.dispatch`` (pallas on TPU, lax elsewhere).
     """
-    if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "lax"
-    if impl == "lax":
+    if pick_impl(impl, "chunk_dedup") == "lax":
         return ref.chunk_dedup_ref(have, digest)
-    if impl != "pallas":
-        raise ValueError(f"unknown chunk_dedup impl: {impl!r}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return chunk_dedup_pallas(have, digest, block_s=block_s, interpret=interpret)
 
 

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import ref
+from repro.kernels.dispatch import interpret_mode, pick_impl
 
 BLOCK = 128    # codec block length (lane-aligned: the f32 TPU tile is (8, 128))
 BLOCK_T = 8    # block rows per pallas grid step
@@ -77,7 +78,7 @@ def quant_blocks_pallas(
     x: jnp.ndarray,          # (nb, B) f32 — one codec block per row
     qmax: int,
     block_t: int = BLOCK_T,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> tuple:
     """Blocked symmetric quantization — the Pallas reduction.
 
@@ -101,7 +102,7 @@ def quant_blocks_pallas(
             jax.ShapeDtypeStruct((nb + pad, b), jnp.int32),
             jax.ShapeDtypeStruct((nb + pad, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp)
     return codes[:nb].astype(jnp.int8), scales[:nb, 0]
 
@@ -124,7 +125,7 @@ def topk_blocks_pallas(
     d: jnp.ndarray,          # (nb, B) f32 — one delta block per row
     k: int,
     block_t: int = BLOCK_T,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     """Per-block top-k-|delta| masking — the Pallas reduction.
 
@@ -141,7 +142,7 @@ def topk_blocks_pallas(
         in_specs=[pl.BlockSpec((bt, b), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bt, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb + pad, b), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(dp)
     return out[:nb]
 
@@ -150,31 +151,19 @@ def quant_blocks(x, qmax: int, impl: str = None, block_t: int = BLOCK_T,
                  interpret: bool = None) -> tuple:
     """Blocked quantization with backend dispatch (the ``chunk_dedup`` rule).
 
-    ``impl``: "pallas" forces the kernel (interpreted off-TPU), "lax" the
-    pure-lax oracle; None picks pallas on TPU, lax elsewhere.
+    ``impl``: "pallas" forces the kernel, "lax" the pure-lax oracle; None
+    follows ``repro.kernels.dispatch`` (pallas on TPU, lax elsewhere).
     """
-    if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "lax"
-    if impl == "lax":
+    if pick_impl(impl, "quant_blocks") == "lax":
         return ref.quant_blocks_ref(x, qmax)
-    if impl != "pallas":
-        raise ValueError(f"unknown quant_blocks impl: {impl!r}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return quant_blocks_pallas(x, qmax, block_t=block_t, interpret=interpret)
 
 
 def topk_blocks(d, k: int, impl: str = None, block_t: int = BLOCK_T,
                 interpret: bool = None) -> jnp.ndarray:
     """Per-block top-k masking with backend dispatch."""
-    if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "lax"
-    if impl == "lax":
+    if pick_impl(impl, "topk_blocks") == "lax":
         return ref.topk_blocks_ref(d, k)
-    if impl != "pallas":
-        raise ValueError(f"unknown topk_blocks impl: {impl!r}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return topk_blocks_pallas(d, k, block_t=block_t, interpret=interpret)
 
 

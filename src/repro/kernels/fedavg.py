@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import interpret_mode
+
 BLOCK_N = 16 * 1024  # 16k f32 lanes x k rows ~= 512 KiB @ k=8 — fits VMEM
 
 
@@ -28,7 +30,7 @@ def fedavg_pallas(
     weights: jnp.ndarray,        # (k,) f32
     models: jnp.ndarray,         # (k, N)
     block_n: int = BLOCK_N,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     k, n = models.shape
     pad = (-n) % block_n
@@ -45,6 +47,6 @@ def fedavg_pallas(
         ],
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), models.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(w, x)
     return out[0, :n]

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dispatch import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -91,7 +93,7 @@ def flash_attention_pallas(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     B, H, S, hd = q.shape
     KV = k.shape[1]
@@ -125,7 +127,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     return out[:, :, :S, :]
 
@@ -183,7 +185,7 @@ def decode_attention_pallas(
     v: jnp.ndarray,        # (B, S, KV, hd)
     lengths: jnp.ndarray,  # (B,) int32
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     B, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]
@@ -217,6 +219,6 @@ def decode_attention_pallas(
             pltpu.VMEM((G, 128), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lens, qg, kk, vv)
     return out.reshape(B, H, hd)

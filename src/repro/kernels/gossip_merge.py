@@ -33,10 +33,10 @@ candidates, so the fused round is bitwise-identical to it (tested by
 ``tests/test_gossip_merge.py``).
 
 The kernel tiles (receivers x cap) — grid step (i, c) loads the (R, block_c)
-key slab once and reduces it against receiver i's mask column. On this
-CPU container ``interpret=True`` drives the same kernel through the Pallas
-interpreter; ``repro.kernels.ref.gossip_winner_ref`` is the pure-lax
-fallback/oracle that production CPU paths route through.
+key slab once and reduces it against receiver i's mask column. It runs
+compiled on TPU and in the Pallas interpreter elsewhere
+(``repro.kernels.dispatch``); ``repro.kernels.ref.gossip_winner_ref`` is
+the pure-lax oracle that CPU paths route through.
 
 Since the mesh-sharded round (PR 3), every entry point is BLOCK-addressed:
 ``mask`` may be a rectangular (Rr, R) receiver block of the full sender
@@ -59,19 +59,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import ref
+from repro.kernels.dispatch import interpret_mode, pick_impl
 
-BLOCK_C = 256   # (R, 256) i32/f32 slabs x 4 inputs: ~400 KiB VMEM @ R=100
+BLOCK_C = 256   # (R, 256) i32/f32 slabs x 3 inputs: ~300 KiB VMEM @ R=100
 
 
 def _winner_kernel(off_ref, mask_ref, t_ref, p_ref, ac_ref, src_ref, ac_out_ref):
     # off_ref: (1, 1) i32 — global sender index of the block's receiver 0
-    # mask_ref: (R, 1) i32 — receiver i's candidate column (self included)
+    # mask_ref: (1, R, 1) i32 — receiver i's candidate column (self included)
     # t_ref/p_ref/ac_ref: (R, bc) — all senders' key/counter slabs
-    # src_ref/ac_out_ref: (1, bc) — winner index + merged counter for row i
-    i = pl.program_id(0)
-    gid = i + off_ref[0, 0]                                  # global receiver id
+    # src_ref/ac_out_ref: (1, 1, bc) — winner index + merged counter for row i
+    gid = off_ref[...] + pl.program_id(0)                    # (1, 1) global id
     r = t_ref.shape[0]
-    m = mask_ref[...] != 0                                   # (R, 1)
+    m = mask_ref[0] != 0                                     # (R, 1)
     p = p_ref[...]
     valid = m & (p >= 0)                                     # occupied candidates
     tm = jnp.where(valid, t_ref[...], -jnp.inf)
@@ -82,10 +82,11 @@ def _winner_kernel(off_ref, mask_ref, t_ref, p_ref, ac_ref, src_ref, ac_out_ref)
     win = tie & (pm == best_p)                               # winning identity
     idx = jax.lax.broadcasted_iota(jnp.int32, win.shape, 0)
     first = jnp.min(jnp.where(win, idx, r), axis=0, keepdims=True)
-    self_win = jnp.any(win & (idx == gid), axis=0, keepdims=True)
+    self_win = jnp.max(jnp.where(win & (idx == gid), 1, 0), axis=0,
+                       keepdims=True) > 0
     src = jnp.where(self_win | (first >= r), gid, first)     # first>=r: all empty
-    src_ref[...] = src.astype(jnp.int32)
-    ac_out_ref[...] = jnp.max(jnp.where(win, ac_ref[...], 0), axis=0, keepdims=True)
+    src_ref[0] = src.astype(jnp.int32)
+    ac_out_ref[0] = jnp.max(jnp.where(win, ac_ref[...], 0), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -95,7 +96,7 @@ def gossip_winner_pallas(
     approval_count: jnp.ndarray,  # (R, cap) i32
     mask: jnp.ndarray,            # (Rr, R) bool — mask[i, j]: i hears j
     block_c: int = BLOCK_C,
-    interpret: bool = True,
+    interpret: bool = None,
     row_offset=0,                 # () i32 — global sender index of receiver 0
 ) -> tuple:
     """(src, ac): per-row winner index and merged approval counter.
@@ -105,10 +106,23 @@ def gossip_winner_pallas(
     all-gathered sender axis, passing the block's global start index as
     ``row_offset`` so self-tie-preference and the all-empty fallback keep
     addressing the receiver's own global row.
+
+    Tiling: a ledger of ``cap <= block_c`` rows is one full-width column
+    block; a wider one is cut into ``block_c``-row blocks (a multiple of
+    the 128-lane tile) with empty padding rows (publisher -1, never a
+    winner). The mask column and the per-receiver outputs travel as
+    (Rr, R, 1) / (Rr, 1, cap) arrays, so every block's last two dims are
+    the array's own — the TPU tiling rule for the receiver axis.
     """
     r, c = publish_time.shape
     rr = mask.shape[0]
-    bc = min(block_c, c) if c else block_c
+    if c <= block_c:
+        bc = max(c, 1)
+    elif block_c % 128:
+        raise ValueError(f"block_c={block_c} must be a multiple of 128 "
+                         f"when it splits cap={c}")
+    else:
+        bc = block_c
     pad = (-c) % bc
     t = jnp.pad(publish_time, ((0, 0), (0, pad)))
     p = jnp.pad(publisher, ((0, 0), (0, pad)), constant_values=-1)
@@ -117,29 +131,29 @@ def gossip_winner_pallas(
     # the receiver is always a candidate (see ref.gossip_winner_ref)
     rows = jnp.arange(rr, dtype=jnp.int32)
     mask = jnp.asarray(mask).at[rows, off + rows].set(True)
-    mask_t = mask.astype(jnp.int32).T                        # column i = receiver i
+    mask_col = mask.astype(jnp.int32)[:, :, None]            # (Rr, R, 1)
 
     src, ac_out = pl.pallas_call(
         _winner_kernel,
         grid=(rr, (c + pad) // bc),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, cb: (0, 0)),
-            pl.BlockSpec((r, 1), lambda i, cb: (0, i)),
+            pl.BlockSpec((1, r, 1), lambda i, cb: (i, 0, 0)),
             pl.BlockSpec((r, bc), lambda i, cb: (0, cb)),
             pl.BlockSpec((r, bc), lambda i, cb: (0, cb)),
             pl.BlockSpec((r, bc), lambda i, cb: (0, cb)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bc), lambda i, cb: (i, cb)),
-            pl.BlockSpec((1, bc), lambda i, cb: (i, cb)),
+            pl.BlockSpec((1, 1, bc), lambda i, cb: (i, 0, cb)),
+            pl.BlockSpec((1, 1, bc), lambda i, cb: (i, 0, cb)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rr, c + pad), jnp.int32),
-            jax.ShapeDtypeStruct((rr, c + pad), jnp.int32),
+            jax.ShapeDtypeStruct((rr, 1, c + pad), jnp.int32),
+            jax.ShapeDtypeStruct((rr, 1, c + pad), jnp.int32),
         ],
-        interpret=interpret,
-    )(off.reshape(1, 1), mask_t, t, p, ac)
-    return src[:, :c], ac_out[:, :c]
+        interpret=interpret_mode(interpret),
+    )(off.reshape(1, 1), mask_col, t, p, ac)
+    return src[:, 0, :c], ac_out[:, 0, :c]
 
 
 def gossip_winner_nbr(
@@ -201,15 +215,12 @@ def gossip_winner(
 ):
     """Winner-selection reduction with backend dispatch.
 
-    ``impl``: "pallas" forces the kernel (interpreted off-TPU), "lax" the
-    pure-lax fallback; None picks pallas on TPU, lax elsewhere (the Pallas
-    interpreter's per-grid-step loop is slower than one fused lax reduction
-    on CPU). ``row_offset`` (() i32) marks ``mask`` as a contiguous receiver
+    ``impl``: "pallas" forces the kernel, "lax" the pure-lax oracle; None
+    follows ``repro.kernels.dispatch`` (pallas on TPU, lax elsewhere).
+    ``row_offset`` (() i32) marks ``mask`` as a contiguous receiver
     block starting at that global sender index — the mesh-sharded round.
     """
-    if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "lax"
-    if impl == "lax":
+    if pick_impl(impl, "gossip_winner") == "lax":
         row_ids = None
         if row_offset is not None:
             rr = mask.shape[0]
@@ -217,10 +228,6 @@ def gossip_winner(
         return ref.gossip_winner_ref(
             publish_time, publisher, approval_count, mask, row_ids=row_ids
         )
-    if impl != "pallas":
-        raise ValueError(f"unknown gossip_winner impl: {impl!r}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return gossip_winner_pallas(
         publish_time, publisher, approval_count, mask,
         block_c=block_c, interpret=interpret,

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import interpret_mode
+
 BLOCK_M = 512
 _LANES = 128
 
@@ -50,7 +52,7 @@ def _bincount_kernel(idx_ref, w_ref, out_ref):
     jax.jit, static_argnames=("num_bins", "block_m", "interpret")
 )
 def hist_bincount_pallas(idx, weights, num_bins, block_m=BLOCK_M,
-                         interpret=True):
+                         interpret=None):
     """(num_bins,) i32 weighted bincount of ``idx`` via the blocked kernel.
 
     ``idx`` i32 (m,) in [0, num_bins); ``weights`` i32 (m,). The batch is
@@ -77,6 +79,6 @@ def hist_bincount_pallas(idx, weights, num_bins, block_m=BLOCK_M,
         ],
         out_specs=pl.BlockSpec((1, nb_pad), lambda b: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, nb_pad), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(idx.reshape(1, m_pad), w.reshape(1, m_pad))
     return out[0, :num_bins]

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import interpret_mode
+
 BLOCK_N = 16 * 1024
 
 
@@ -32,7 +34,7 @@ def _dist_kernel(x_ref, o_ref):
 def model_distance_pallas(
     models: jnp.ndarray,         # (k, N)
     block_n: int = BLOCK_N,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     k, n = models.shape
     pad = (-n) % block_n
@@ -45,5 +47,5 @@ def model_distance_pallas(
         in_specs=[pl.BlockSpec((k, block_n), lambda i: (0, i))],
         out_specs=pl.BlockSpec((k, k), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((k, k), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dispatch import interpret_mode
+
 CHUNK = 32
 
 
@@ -84,7 +86,7 @@ def wkv_pallas(
     logw: jnp.ndarray,   # (B, T, H, hd), log decay <= 0
     u: jnp.ndarray,      # (H, hd)
     chunk: int = CHUNK,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     B, T, H, hd = r.shape
     assert T % chunk == 0, (T, chunk)
@@ -109,6 +111,6 @@ def wkv_pallas(
         out_specs=pl.BlockSpec((1, 1, chunk, hd), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, hd), r.dtype),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(rr, kk, vv, ww, u)
     return jnp.moveaxis(out, 1, 2)                 # back to (B, T, H, hd)

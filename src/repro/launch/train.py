@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_pytree
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch, list_archs
 from repro.configs.base import DagFLConfig, ModelConfig, TrainConfig
 from repro.data.pipeline import TokenSampler
@@ -57,13 +58,16 @@ def run(
     model = build_model(cfg)
     tcfg = TrainConfig(optimizer="sgd", learning_rate=lr)
     dcfg = DagFLConfig(num_nodes=nodes, alpha=min(4, nodes), k=2, tau_max=1e9)
+    # the loop rebinds the stacked params and the frontier every step, so
+    # the step may reuse their buffers (a full-width model then fits one chip)
     step_fn = jax.jit(
-        fl_lib.make_dagfl_train_step(model, cfg, tcfg, dcfg, nodes)
+        fl_lib.make_dagfl_train_step(model, cfg, tcfg, dcfg, nodes),
+        donate_argnums=(0, 1),
     )
 
     key = jax.random.PRNGKey(seed)
     init_keys = jax.random.split(key, nodes)
-    stacked = jax.vmap(model.init)(init_keys)
+    stacked = jax.jit(jax.vmap(model.init))(init_keys)
     n_params = sum(p.size for p in jax.tree_util.tree_leaves(stacked)) // nodes
     print(f"arch={cfg.name} params/node={n_params/1e6:.1f}M nodes={nodes} "
           f"batch/node={batch_per_node} seq={seq_len}")
@@ -77,6 +81,7 @@ def run(
     val_tokens = jnp.stack([jnp.asarray(val.next()["tokens"][0]) for _ in range(nodes)])
     val_batch = {"tokens": val_tokens[:, None, :]}
 
+    metrics = {}
     t0 = time.time()
     for step in range(steps):
         toks = np.stack([s.next()["tokens"] for s in samplers])   # (N, b, S)
@@ -101,7 +106,7 @@ def run(
         save_pytree(checkpoint, {"params": stacked, "frontier": frontier},
                     meta={"arch": cfg.name, "steps": steps})
         print(f"checkpoint -> {checkpoint}.npz")
-    return stacked, frontier
+    return stacked, frontier, metrics
 
 
 def main():
@@ -115,6 +120,7 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--checkpoint", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "100m":
         cfg = small_100m()

@@ -308,7 +308,3 @@ def missing_chunks(dags: DagState, bstate: BankState,
     sat = ck.chunk_dedup(bstate.have, digest, impl=impl)
     ref = referenced_slots(dags, sat.shape[1])
     return jnp.sum((ref[:, :, None] & ~sat).astype(jnp.int32), axis=(1, 2))
-
-
-missing_chunks_jit = jax.jit(missing_chunks, static_argnames=("impl",))
-gate_view_jit = jax.jit(gate_view)

@@ -88,6 +88,7 @@ from repro.kernels import chunk_transfer as chunk_kernel
 from repro.kernels.event_pop import event_pop
 from repro.net import bank as bank_lib
 from repro.net import gossip as gossip_lib
+from repro.net import mesh as mesh_lib
 from repro.net import replica as replica_lib
 from repro.net.topology import Topology, partition_matrix
 
@@ -813,7 +814,7 @@ def simulate_insystem_tips(
         staleness=np.asarray(tst, np.float64)[:cur],
         published=int(seqc) - 1,
         overflow=int(ovf),
-        union=replica_lib.merge_all_jit(dags),
+        union=mesh_lib.replicated_jit(replica_lib.merge_all, None)(dags),
         trace=span_trace,
         trace_dropped=span_dropped,
     )

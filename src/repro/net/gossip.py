@@ -98,7 +98,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import dag as dag_lib
@@ -106,6 +105,7 @@ from repro.core.dag import DagState
 from repro.kernels import chunk_transfer as chunk_kernel
 from repro.kernels import delta_codec as codec_lib
 from repro.kernels import gossip_merge as gossip_kernel
+from repro.kernels.dispatch import pick_impl
 from repro.net import bank as bank_lib
 from repro.net import mesh as mesh_lib
 from repro.net import replica as replica_lib
@@ -246,7 +246,7 @@ def _round_fused(
     rows); the defaults are the identity block — every receiver, offset 0.
     """
     if impl == "fused":
-        impl = "pallas" if jax.default_backend() == "tpu" else "lax"
+        impl = pick_impl(None, "gossip round")
     senders = dags if senders is None else senders
     rb = dags.publisher.shape[0]
     rows = jnp.arange(rb, dtype=jnp.int32)
@@ -257,8 +257,7 @@ def _round_fused(
         mask = jnp.asarray(edge_active).at[jnp.arange(rb), rows].set(True)
         src, _ = gossip_kernel.gossip_winner_pallas(
             senders.publish_time, senders.publisher, senders.approval_count,
-            mask, interpret=jax.default_backend() != "tpu",
-            row_offset=0 if row_offset is None else row_offset,
+            mask, row_offset=0 if row_offset is None else row_offset,
         )
         return dag_lib.merge_select(senders, src, mask=mask)
     if impl != "lax":
@@ -329,12 +328,12 @@ def _shard_round_block(
 def _shard_round(impl: str, mesh):
     """shard_map'd round: receivers split over "nodes", everything else
     replicated (any extra mesh axes — e.g. "model" — replicate too)."""
-    return shard_map(
+    return jax.shard_map(
         functools.partial(_shard_round_block, impl=impl),
         mesh=mesh,
         in_specs=(P(mesh_lib.NODES_AXIS), P(), P(), P()),
         out_specs=P(mesh_lib.NODES_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -409,13 +408,13 @@ def _bank_tick_block(dags, have, credit, sent, digest, edges, nbr_idx,
 @functools.lru_cache(maxsize=None)
 def _shard_bank_tick(impl: str, bank_impl, mesh):
     p_nodes, p_rep = P(mesh_lib.NODES_AXIS), P()
-    return shard_map(
+    return jax.shard_map(
         functools.partial(_bank_tick_block, impl=impl, bank_impl=bank_impl),
         mesh=mesh,
         in_specs=(p_nodes, p_nodes, p_nodes, p_nodes,
                   p_rep, p_rep, p_rep, p_rep, p_rep, p_rep),
         out_specs=(p_nodes, p_nodes, p_nodes, p_nodes),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -456,6 +455,25 @@ def _codec_tick(tick, codec):
                     cap_bytes, chunk_bytes * ratio)
 
     return run
+
+
+def _observer(obs, mesh=None, bank_impl=None):
+    """``obs_lib.observe_round`` for the tick loops, ``obs`` and
+    ``bank_impl`` bound. Under a mesh it runs replicated
+    (``mesh_lib.replicated``): the telemetry reaches kernels (the union
+    fold, chunk accounting, histogram bincount) that XLA cannot partition
+    over the sharded receiver axis."""
+    from repro import obs as obs_lib   # deferred: repro.obs imports repro.net
+
+    def observe(metrics, ring, t, old, new, live_edges, bytes_delta=None,
+                bstate=None, digest=None, old_have=None):
+        return obs_lib.observe_round(
+            obs, metrics, ring, t, old, new, live_edges=live_edges,
+            bytes_delta=bytes_delta, bstate=bstate, digest=digest,
+            bank_impl=bank_impl, old_have=old_have,
+        )
+
+    return observe if mesh is None else mesh_lib.replicated(observe, mesh)
 
 
 @functools.lru_cache(maxsize=None)
@@ -499,7 +517,7 @@ def _advance_bank_jit(impl: str, bank_impl, mesh=None, obs=None, faults=None,
 
         return jax.jit(advance)
 
-    from repro import obs as obs_lib
+    observe = _observer(obs, mesh, bank_impl)
 
     def advance(dags, bstate, digest, key, ticks, part_active, adj, drop,
                 stride, part_mask, nbr_idx, nbr_valid, cap_bytes, chunk_bytes,
@@ -513,11 +531,9 @@ def _advance_bank_jit(impl: str, bank_impl, mesh=None, obs=None, faults=None,
             new, newb = tick(dags, bstate, digest, edges, nbr_idx,
                              nbr_valid, cap_bytes, chunk_bytes)
             t = (tick_i.astype(jnp.float32) + 1.0) * period
-            metrics, ring = obs_lib.observe_round(
-                obs, metrics, ring, t, dags, new, live_edges=edges,
-                bytes_delta=newb.sent - bstate.sent, bstate=newb,
-                digest=digest, bank_impl=bank_impl, old_have=bstate.have,
-            )
+            metrics, ring = observe(metrics, ring, t, dags, new, edges,
+                                    newb.sent - bstate.sent, newb, digest,
+                                    bstate.have)
             return (new, newb, key, metrics, ring), None
 
         (dags, bstate, key, metrics, ring), _ = jax.lax.scan(
@@ -583,7 +599,7 @@ def _converge_bank_jit(impl: str, bank_impl, mesh=None, obs=None, faults=None,
 
         return jax.jit(converge)
 
-    from repro import obs as obs_lib
+    observe = _observer(obs, mesh, bank_impl)
 
     def converge(dags, bstate, digest, key, tick0, part_mask, adj, drop,
                  stride, limit, stall_limit, nbr_idx, nbr_valid, cap_bytes,
@@ -605,11 +621,9 @@ def _converge_bank_jit(impl: str, bank_impl, mesh=None, obs=None, faults=None,
             still = trees_equal((new, newb), (dags, bstate))
             stalled = jnp.where(still, stalled + 1, 0)
             t = (tick_i.astype(jnp.float32) + 1.0) * period
-            metrics, ring = obs_lib.observe_round(
-                obs, metrics, ring, t, dags, new, live_edges=edges,
-                bytes_delta=newb.sent - bstate.sent, bstate=newb,
-                digest=digest, bank_impl=bank_impl, old_have=bstate.have,
-            )
+            metrics, ring = observe(metrics, ring, t, dags, new, edges,
+                                    newb.sent - bstate.sent, newb, digest,
+                                    bstate.have)
             return (new, newb, key, tick_i + 1, stalled, done + 1,
                     metrics, ring)
 
@@ -707,7 +721,7 @@ def _advance_jit(impl: str, mesh=None, obs=None, faults=None):
 
         return jax.jit(advance)
 
-    from repro import obs as obs_lib   # deferred: repro.obs imports repro.net
+    observe = _observer(obs, mesh)
 
     def advance(dags, key, ticks, part_active, adj, drop, stride, part_mask,
                 nbr_idx, nbr_valid, metrics, ring, period):
@@ -719,9 +733,7 @@ def _advance_jit(impl: str, mesh=None, obs=None, faults=None):
             edges = _sample_edges(sub, tick, pm, adj, drop, stride)
             new = apply_round(dags, edges, nbr_idx, nbr_valid)
             t = (tick.astype(jnp.float32) + 1.0) * period
-            metrics, ring = obs_lib.observe_round(
-                obs, metrics, ring, t, dags, new, live_edges=edges
-            )
+            metrics, ring = observe(metrics, ring, t, dags, new, edges)
             return (new, key, metrics, ring), None
 
         (dags, key, metrics, ring), _ = jax.lax.scan(
@@ -779,7 +791,7 @@ def _converge_jit(impl: str, mesh=None, obs=None, faults=None):
 
         return jax.jit(converge)
 
-    from repro import obs as obs_lib
+    observe = _observer(obs, mesh)
 
     def converge(dags, key, tick, part_mask, adj, drop, stride, limit,
                  stall_limit, nbr_idx, nbr_valid, metrics, ring, period):
@@ -798,9 +810,7 @@ def _converge_jit(impl: str, mesh=None, obs=None, faults=None):
             new = apply_round(dags, edges, nbr_idx, nbr_valid)
             stalled = jnp.where(trees_equal(new, dags), stalled + 1, 0)
             t = (tick.astype(jnp.float32) + 1.0) * period
-            metrics, ring = obs_lib.observe_round(
-                obs, metrics, ring, t, dags, new, live_edges=edges
-            )
+            metrics, ring = observe(metrics, ring, t, dags, new, edges)
             return (new, key, tick + 1, stalled, done + 1, metrics, ring)
 
         dags, key, tick, _, done, metrics, ring = jax.lax.while_loop(
@@ -1062,9 +1072,8 @@ class GossipNetwork:
         dag = replica_lib.read_replica(self.replicas, i)
         if self.bank_cfg is None:
             return dag
-        return bank_lib.gate_view_jit(
-            dag, self.replicas.bank_state.have[i], self._digest
-        )
+        gate = mesh_lib.replicated_jit(bank_lib.gate_view, self.mesh)
+        return gate(dag, self.replicas.bank_state.have[i], self._digest)
 
     def bank_commit(self, node_id, slot, params) -> None:
         """Account a stage-4 commit in the transport state: the committer
@@ -1087,10 +1096,10 @@ class GossipNetwork:
         behind row visibility (all zeros without bank gossip)."""
         if self.bank_cfg is None:
             return np.zeros(self.topology.num_nodes, np.int32)
-        return np.asarray(bank_lib.missing_chunks_jit(
-            self.replicas.dags, self.replicas.bank_state, self._digest,
-            impl=self.bank_cfg.impl,
-        ))
+        count = mesh_lib.replicated_jit(bank_lib.missing_chunks, self.mesh,
+                                        impl=self.bank_cfg.impl)
+        return np.asarray(count(
+            self.replicas.dags, self.replicas.bank_state, self._digest))
 
     def bytes_sent(self) -> float:
         """Total payload bytes delivered so far (the Table-I traffic bill)."""
@@ -1099,7 +1108,8 @@ class GossipNetwork:
         return float(jnp.sum(self.replicas.bank_state.sent))
 
     def union(self) -> DagState:
-        return replica_lib.merge_all_jit(self.replicas.dags)
+        merge = mesh_lib.replicated_jit(replica_lib.merge_all, self.mesh)
+        return merge(self.replicas.dags)
 
     def synced(self) -> bool:
         """Fully converged: row-identical replicas AND — when the bank is

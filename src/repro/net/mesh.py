@@ -31,6 +31,7 @@ paths bitwise.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
@@ -99,3 +100,26 @@ def replicate(x: Any, mesh: Mesh) -> Any:
     """Place overlay-wide arrays (adjacency, drop, strides) fully replicated
     so the jitted sync loops see one committed layout per mesh."""
     return jax.device_put(x, NamedSharding(mesh, P()))
+
+
+def replicated(fn, mesh: Mesh) -> Any:
+    """``fn`` run whole on every device of ``mesh``.
+
+    XLA cannot partition a Pallas kernel: a multi-device program may call
+    one only inside a ``shard_map``. Code over the sharded replicas that
+    reaches a kernel outside the sharded round (the union fold, view
+    gating, chunk accounting, telemetry) therefore runs as a ``shard_map``
+    whose inputs are gathered in full, each device computing the same
+    replicated result. ``fn`` takes arrays only, positionally.
+    """
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
+@functools.lru_cache(maxsize=None)
+def replicated_jit(fn, mesh: Optional[Mesh], **static) -> Any:
+    """``replicated(fn, mesh)`` jitted, for host-side reads (``mesh=None``:
+    plain ``jax.jit(fn)``); ``static`` binds keyword arguments that select
+    code paths (e.g. ``impl``)."""
+    body = functools.partial(fn, **static) if static else fn
+    return jax.jit(body if mesh is None else replicated(body, mesh))

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from repro.core import dag as dag_lib
 from repro.core.dag import DagState
-from repro.kernels import ref as kernel_ref
+from repro.kernels import gossip_merge
 
 
 class ReplicaSet(NamedTuple):
@@ -141,13 +141,13 @@ def merge_all(dags: DagState) -> DagState:
     external agent E) would see, and equals the shared-ledger state when the
     overlay is fully synchronized. Implemented as the same fused winner
     reduction the anti-entropy round uses (one receiver hearing every
-    replica — the ``Rr=1`` case of ``kernels.ref.gossip_winner_ref``), which
+    replica — the ``Rr=1`` case of ``kernels.gossip_merge.gossip_winner``), which
     is bitwise-equal to the sequential fold: the reduction's replica-0 tie
     preference is exactly the fold's first-element preference.
     """
     r = dags.publisher.shape[0]
     mask = jnp.ones((1, r), bool)
-    src, _ = kernel_ref.gossip_winner_ref(
+    src, _ = gossip_merge.gossip_winner(
         dags.publish_time, dags.publisher, dags.approval_count, mask
     )
     merged = dag_lib.merge_select(dags, src, mask=mask)
@@ -199,6 +199,5 @@ def replicas_synced(dags: DagState) -> jnp.ndarray:
 
 # Module-level jitted entry points: one trace per leaf structure/shape, no
 # matter how many GossipNetwork instances a benchmark sweep constructs.
-merge_all_jit = jax.jit(merge_all)
 missing_vs_union_jit = jax.jit(missing_vs_union)
 replicas_synced_jit = jax.jit(replicas_synced)

@@ -82,10 +82,13 @@ def test_fused_round_matches_scan_on_random_states(impl):
 
 def test_pallas_kernel_matches_lax_oracle_all_block_widths():
     """The Pallas kernel (interpret mode here) against the pure-lax oracle,
-    including a block width that forces column padding."""
+    for every tile-legal block width: one full-width block, and 128- /
+    256-row blocks that force column padding; plus a width the TPU tiling
+    rule refuses."""
     rng = np.random.default_rng(1)
-    for bc in (4, 8, 16, 64):          # 64 > CAP: single padded block
-        dags = random_stacked(rng, 7)
+    cap = 300
+    for bc in (128, 256, 512):         # 512 > cap: single full-width block
+        dags = random_stacked(rng, 7, cap=cap)
         mask = jnp.asarray(rng.random((7, 7)) < 0.5) | jnp.eye(7, dtype=bool)
         ref_out = kref.gossip_winner_ref(
             dags.publish_time, dags.publisher, dags.approval_count, mask
@@ -96,6 +99,11 @@ def test_pallas_kernel_matches_lax_oracle_all_block_widths():
         )
         for a, b in zip(ref_out, pal_out):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        gossip_winner_pallas(
+            dags.publish_time, dags.publisher, dags.approval_count, mask,
+            block_c=64, interpret=True,
+        )
 
 
 @settings(max_examples=25, deadline=None)

@@ -358,9 +358,7 @@ def test_property_infinite_bandwidth_bitwise(seed, overlay, impl, split):
         b.advance(t)
         assert_dags_equal(a.replicas.dags, b.replicas.dags, msg=f"t={t}:")
         # payload availability == row visibility in the infinite-bw limit
-        sat = np.asarray(bank_lib.missing_chunks_jit(
-            b.replicas.dags, b.replicas.bank_state, b._digest, impl=None))
-        assert sat.max() == 0
+        assert b.missing_chunks().max() == 0
     assert a.converge(at_time=20.0) == b.converge(at_time=20.0)
     assert_dags_equal(a.replicas.dags, b.replicas.dags, msg="converge:")
 

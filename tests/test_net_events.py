@@ -31,7 +31,7 @@ from _hypothesis_compat import given, settings, st
 from repro.core import dag as dag_lib
 from repro.core import stability
 from repro.configs.base import DagFLConfig
-from repro.kernels import event_pop as pop_kernel
+from repro.kernels.event_pop import event_pop_pallas
 from repro.kernels import ref as kernel_ref
 from repro.net import events as events_lib
 from repro.net import gossip as gossip_lib
@@ -65,19 +65,20 @@ def test_event_pop_ref_tie_breaks():
 
 
 @settings(max_examples=40, deadline=None)
-@given(seed=st.integers(0, 2**31 - 1), q=st.integers(1, 70),
-       block_q=st.sampled_from([4, 16, 512]))
+@given(seed=st.integers(0, 2**31 - 1), q=st.integers(1, 2600),
+       block_q=st.sampled_from([1024, 2048, 16384]))
 def test_property_event_pop_pallas_matches_ref(seed, q, block_q):
     """Property: kernel == oracle, including duplicate (time, kind, seq)
-    keys (first-slot tie-break) and all-invalid queues."""
+    keys across slabs (first-slot tie-break), padded tails and
+    all-invalid queues."""
     rng = np.random.default_rng(seed)
     t = rng.choice([0.25, 1.0, 1.5, 7.75], q).astype(np.float32)
     k = rng.integers(0, 4, q).astype(np.int32)
     s = rng.integers(0, 6, q).astype(np.int32)
-    v = rng.random(q) < 0.5
+    v = rng.random(q) < rng.choice([0.0, 0.01, 0.5])
     args = (jnp.asarray(t), jnp.asarray(k), jnp.asarray(s), jnp.asarray(v))
     ri, rf = kernel_ref.event_pop_ref(*args)
-    pi, pf = pop_kernel.event_pop_pallas(*args, block_q=block_q)
+    pi, pf = event_pop_pallas(*args, block_q=block_q, interpret=True)
     assert bool(rf) == bool(pf)
     assert int(ri) == int(pi)
 
