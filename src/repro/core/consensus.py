@@ -128,36 +128,44 @@ def make_dagfl_stages(
     validator = val_lib.make_validator(eval_fn)
 
     def prepare(dag, bank, now, key, train_batch, val_batch, node_bias=None) -> Prepared:
-        k_sel, k_train = jax.random.split(key)
-        rows, nvalid = dag_lib.select_tips(
-            dag, k_sel, cfg.alpha, now, cfg.tau_max, node_bias=node_bias
-        )
-        slots = jnp.where(rows >= 0, dag.model_slot[jnp.maximum(rows, 0)], -1)
-        auth_ok = val_lib.authenticate(dag.auth_tag, bank, slots)
-        accs = jnp.where(auth_ok, validator(bank, slots, val_batch), -jnp.inf)
-        chosen_slots, top_pos, top_acc = val_lib.select_top_k(accs, slots, cfg.k)
-        chosen_rows = jnp.where(
-            jnp.isfinite(top_acc), rows[top_pos], dag_lib.NO_TX
-        ).astype(jnp.int32)
-        n_chosen = jnp.sum(chosen_slots >= 0)
-
-        if weighted:
-            stale = now - dag.publish_time[jnp.maximum(chosen_rows, 0)]
-            weights = agg.staleness_accuracy_weights(
-                jnp.where(jnp.isfinite(top_acc), top_acc, 0.0), stale, cfg.tau_max
+        # named scopes (``dagfl/<stage>``) tag each stage's ops in the
+        # compiled program's metadata, so a device profile can split
+        # prepare's time by stage; they add no operations
+        with jax.named_scope("dagfl/select"):
+            k_sel, k_train = jax.random.split(key)
+            rows, nvalid = dag_lib.select_tips(
+                dag, k_sel, cfg.alpha, now, cfg.tau_max, node_bias=node_bias
             )
-        else:
-            weights = agg.uniform_weights(cfg.k)
-        aggregated = bank_lib.bank_average(bank, chosen_slots, weights)
-        last_slot = dag.model_slot[jnp.mod(dag.count - 1, dag_lib.capacity_of(dag))]
-        fallback = bank_lib.bank_read(bank, jnp.maximum(last_slot, 0))
-        global_model = jax.tree_util.tree_map(
-            lambda a, f: jnp.where(n_chosen > 0, a, f), aggregated, fallback
-        )
-        new_params = global_model
-        for _ in range(cfg.beta):
-            new_params, _ = train_fn(new_params, train_batch, k_train)
-        new_acc = eval_fn(new_params, val_batch).astype(jnp.float32)
+            slots = jnp.where(rows >= 0, dag.model_slot[jnp.maximum(rows, 0)], -1)
+        with jax.named_scope("dagfl/validate"):
+            auth_ok = val_lib.authenticate(dag.auth_tag, bank, slots)
+            accs = jnp.where(auth_ok, validator(bank, slots, val_batch), -jnp.inf)
+            chosen_slots, top_pos, top_acc = val_lib.select_top_k(accs, slots, cfg.k)
+            chosen_rows = jnp.where(
+                jnp.isfinite(top_acc), rows[top_pos], dag_lib.NO_TX
+            ).astype(jnp.int32)
+            n_chosen = jnp.sum(chosen_slots >= 0)
+
+        with jax.named_scope("dagfl/aggregate"):
+            if weighted:
+                stale = now - dag.publish_time[jnp.maximum(chosen_rows, 0)]
+                weights = agg.staleness_accuracy_weights(
+                    jnp.where(jnp.isfinite(top_acc), top_acc, 0.0), stale, cfg.tau_max
+                )
+            else:
+                weights = agg.uniform_weights(cfg.k)
+            aggregated = bank_lib.bank_average(bank, chosen_slots, weights)
+            last_slot = dag.model_slot[jnp.mod(dag.count - 1, dag_lib.capacity_of(dag))]
+            fallback = bank_lib.bank_read(bank, jnp.maximum(last_slot, 0))
+            global_model = jax.tree_util.tree_map(
+                lambda a, f: jnp.where(n_chosen > 0, a, f), aggregated, fallback
+            )
+        with jax.named_scope("dagfl/train"):
+            new_params = global_model
+            for _ in range(cfg.beta):
+                new_params, _ = train_fn(new_params, train_batch, k_train)
+        with jax.named_scope("dagfl/validate"):
+            new_acc = eval_fn(new_params, val_batch).astype(jnp.float32)
         return Prepared(new_params, chosen_rows, new_acc, nvalid)
 
     return prepare, commit_prepared
@@ -180,17 +188,18 @@ def commit_prepared(dag, bank, node_id, t_publish, prepared: Prepared,
     elif new_count is None:
         raise ValueError("commit_prepared: slot and new_count go together "
                          "(see repro.net.replica.global_row)")
-    tag = bank_lib.auth_checksum(prepared.new_params)
-    bank = bank_lib.bank_write(bank, slot, prepared.new_params)
-    dag = dag_lib.publish_at(
-        dag,
-        slot,
-        new_count,
-        jnp.asarray(node_id, jnp.int32),
-        jnp.asarray(t_publish, jnp.float32),
-        prepared.chosen_rows,
-        prepared.new_accuracy,
-        tag,
-        slot,
-    )
+    with jax.named_scope("dagfl/commit"):
+        tag = bank_lib.auth_checksum(prepared.new_params)
+        bank = bank_lib.bank_write(bank, slot, prepared.new_params)
+        dag = dag_lib.publish_at(
+            dag,
+            slot,
+            new_count,
+            jnp.asarray(node_id, jnp.int32),
+            jnp.asarray(t_publish, jnp.float32),
+            prepared.chosen_rows,
+            prepared.new_accuracy,
+            tag,
+            slot,
+        )
     return dag, bank

@@ -20,6 +20,12 @@ from repro.core import dag as dag_lib
 from repro.core import validation as val_lib
 
 
+def host_read(label: str, x):
+    """Blocking device->host read of ``x``; ``label`` names the read for
+    callers that count them (``GossipNetwork._fetch``)."""
+    return jax.device_get(x)
+
+
 @dataclass
 class ControllerState:
     dag: dag_lib.DagState
@@ -62,23 +68,29 @@ class Controller:
         )
         return ControllerState(dag=dag, bank=bank)
 
-    def check(self, state: ControllerState, key, now: float, val_batch) -> ControllerState:
+    def check(self, state: ControllerState, key, now: float, val_batch,
+              fetch: Callable[[str, Any], Any] = None) -> ControllerState:
         """One Algorithm-1 loop body: validate alpha tips, build omega_0,
-        test ACC_t >= ACC_0."""
+        test ACC_t >= ACC_0.
+
+        ``fetch(label, x)`` makes the check's two blocking device->host
+        reads (``host_read`` by default; the gossip driver passes its
+        counting funnel, ``GossipNetwork._fetch``)."""
+        fetch = fetch or host_read
         rows, _ = dag_lib.select_tips(
             state.dag, key, self.cfg.alpha, jnp.asarray(now, jnp.float32), self.cfg.tau_max
         )
         slots = jnp.where(rows >= 0, state.dag.model_slot[jnp.maximum(rows, 0)], -1)
         accs = self.validator(state.bank, slots, val_batch)
         chosen, _, top_acc = val_lib.select_top_k(accs, slots, self.cfg.k)
-        n_ok = int(jnp.sum(chosen >= 0))
+        n_ok = int(fetch("check", jnp.sum(chosen >= 0)))
         if n_ok == 0:
             state.checks += 1
             return state
         model = bank_lib.bank_average(
             state.bank, chosen, agg.uniform_weights(self.cfg.k)
         )
-        acc_t = float(self.eval_fn(model, val_batch))
+        acc_t = float(fetch("check", self.eval_fn(model, val_batch)))
         state.checks += 1
         if acc_t > state.best_accuracy:
             state.best_accuracy = acc_t

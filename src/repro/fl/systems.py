@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.configs.base import DagFLConfig
 from repro.core import Controller, make_dagfl_iteration
+from repro.core.controller import host_read
 from repro.core.consensus import commit_prepared, make_dagfl_stages
 from repro.core.anomaly import contribution_rates
 from repro.fl.latency import LatencyModel
@@ -81,12 +82,13 @@ def _jb(batch: Dict[str, np.ndarray]) -> Dict[str, jnp.ndarray]:
     return {k: jnp.asarray(v) for k, v in batch.items()}
 
 
-def _counter_snapshot(dag) -> Dict[str, np.ndarray]:
-    """Raw cumulative counters (Table IV) at a point in time."""
+def _counter_snapshot(dag) -> Dict[str, jnp.ndarray]:
+    """Raw cumulative counters (Table IV) at a point in time, on the device
+    (the driver reads them back through its backend's ``fetch``)."""
     return dict(
-        contribution_m0=np.asarray(dag.contributing_m0),
-        contribution_m1=np.asarray(dag.contributing_m1),
-        published=np.asarray(dag.published_per_node),
+        contribution_m0=dag.contributing_m0,
+        contribution_m1=dag.contributing_m1,
+        published=dag.published_per_node,
     )
 
 
@@ -169,6 +171,8 @@ class _SharedLedger:
     def advance(self, t):
         pass
 
+    fetch = staticmethod(host_read)
+
     def commit(self, node_id, t1, prepared):
         self.dag, self.bank = self._commit(
             self.dag, self.bank, node_id, jnp.float32(t1), prepared
@@ -191,7 +195,14 @@ def _run_dagfl_events(task, nodes, dcfg, sim, global_val, weighted, make_backend
     Eq.-4 equilibrium instead of being consumed serially. The backend
     decides what ledger state a node sees (global vs its own replica);
     keeping one copy of the loop is what guarantees the gossip system's
-    ideal-wire limit stays exactly equivalent to the shared ledger."""
+    ideal-wire limit stays exactly equivalent to the shared ledger.
+
+    Wall-clock spans (``jax.profiler.TraceAnnotation``, on the profiler's
+    clock when one records): ``repro.fl.start`` and ``repro.fl.commit``
+    carry the iteration index that pairs them, ``repro.fl.check`` wraps
+    the agent's check, and a start splits into ``repro.fl.inputs`` (the
+    time, key, batches and bias it passes) and ``repro.fl.prepare`` (the
+    dispatch)."""
     rng = np.random.default_rng(sim.seed)
     lat = LatencyModel.create(dcfg, sim.seed)
     gv = _jb(global_val)
@@ -230,60 +241,67 @@ def _run_dagfl_events(task, nodes, dcfg, sim, global_val, weighted, make_backend
     done = 0
     mid_snapshot = {}
 
-    def _commit_one(t1, nid, prepared):
+    def _commit_one(t1, i, nid, prepared):
         nonlocal done
-        backend.advance(t1)
-        backend.commit(nid, t1, prepared)
-        done += 1
-        if done == sim.iterations // 2 and not mid_snapshot:
-            mid_snapshot.update(_counter_snapshot(backend.union_dag()))
+        with jax.profiler.TraceAnnotation("repro.fl.commit", iteration=i):
+            backend.advance(t1)
+            backend.commit(nid, t1, prepared)
+            done += 1
+            if done == sim.iterations // 2 and not mid_snapshot:
+                mid_snapshot.update(backend.fetch(
+                    "snapshot", _counter_snapshot(backend.union_dag())))
 
     def _check(t1):
         nonlocal state
-        union = backend.union_dag()
-        state.dag, state.bank = union, backend.bank
-        state = ctrl.check(state, jax.random.PRNGKey(done), float(t1) + 1e-3, gv)
-        curve.append((done, t1, state.best_accuracy))
-        backend.observe(done, t1, union)
+        with jax.profiler.TraceAnnotation("repro.fl.check"):
+            union = backend.union_dag()
+            state.dag, state.bank = union, backend.bank
+            state = ctrl.check(state, jax.random.PRNGKey(done), float(t1) + 1e-3,
+                               gv, fetch=backend.fetch)
+            curve.append((done, t1, state.best_accuracy))
+            backend.observe(done, t1, union)
 
     for i, t0 in enumerate(starts):
         while pending and pending[0][0] <= t0:
-            t1, _, nid, prepared = heapq.heappop(pending)
-            _commit_one(t1, nid, prepared)
+            t1, seq, nid, prepared = heapq.heappop(pending)
+            _commit_one(t1, seq, nid, prepared)
             if done % sim.eval_every == 0:
                 _check(t1)
-        backend.advance(t0)
-        node = nodes[rng.integers(0, N)]
-        lazy = node.behavior == "lazy"
-        t1 = t0 + lat.dagfl_iteration(node.node_id, lazy=lazy)
-        # telemetry hook: backends with an event trace record the iteration
-        # span (PUBLISH at t0, duration t1 - t0) — a host-side note, free
-        on_start = getattr(backend, "on_start", None)
-        if on_start is not None:
-            on_start(node.node_id, t0, t1)
-        fn = prep_lazy if lazy else prep_normal
-        bias = bd_bias if node.behavior == "backdoor" else zero_bias
-        # defense hook: backends carrying fault state fold their rejection
-        # credit into tip selection — log(1.0) = 0 for clean senders, so
-        # without rejections this adds an exact zero and the trajectory is
-        # untouched
-        fb = getattr(backend, "fault_bias", lambda: None)()
-        if fb is not None:
-            bias = bias + fb
-        prepared = fn(
-            backend.view(node.node_id),
-            backend.bank,
-            jnp.float32(t0),
-            jax.random.PRNGKey(sim.seed * 100003 + i),
-            _jb(node.epoch(sim.steps_per_iter, sim.minibatch)),
-            _jb(node.val_batch(sim.val_size)),
-            bias,
-        )
-        heapq.heappush(pending, (t1, i, node.node_id, prepared))
-        lats.append(t1 - t0)
+        with jax.profiler.TraceAnnotation("repro.fl.start", iteration=i):
+            backend.advance(t0)
+            node = nodes[rng.integers(0, N)]
+            lazy = node.behavior == "lazy"
+            t1 = t0 + lat.dagfl_iteration(node.node_id, lazy=lazy)
+            # telemetry hook: backends with an event trace record the
+            # iteration span (PUBLISH at t0, duration t1 - t0) — a
+            # host-side note, free
+            on_start = getattr(backend, "on_start", None)
+            if on_start is not None:
+                on_start(node.node_id, t0, t1)
+            fn = prep_lazy if lazy else prep_normal
+            view = backend.view(node.node_id)
+            # the training batch is drawn before the validation batch: the
+            # node's RNG stream, and so the trajectory, depends on the order
+            with jax.profiler.TraceAnnotation("repro.fl.inputs"):
+                now = jnp.float32(t0)
+                key = jax.random.PRNGKey(sim.seed * 100003 + i)
+                batch = _jb(node.epoch(sim.steps_per_iter, sim.minibatch))
+                val = _jb(node.val_batch(sim.val_size))
+                bias = bd_bias if node.behavior == "backdoor" else zero_bias
+                # defense hook: backends carrying fault state fold their
+                # rejection credit into tip selection — log(1.0) = 0 for
+                # clean senders, so without rejections this adds an exact
+                # zero and the trajectory is untouched
+                fb = getattr(backend, "fault_bias", lambda: None)()
+                if fb is not None:
+                    bias = bias + fb
+            with jax.profiler.TraceAnnotation("repro.fl.prepare"):
+                prepared = fn(view, backend.bank, now, key, batch, val, bias)
+            heapq.heappush(pending, (t1, i, node.node_id, prepared))
+            lats.append(t1 - t0)
     while pending:
-        t1, _, nid, prepared = heapq.heappop(pending)
-        _commit_one(t1, nid, prepared)
+        t1, seq, nid, prepared = heapq.heappop(pending)
+        _commit_one(t1, seq, nid, prepared)
     _check(t1)
 
     union = state.dag
@@ -368,6 +386,9 @@ class _GossipLedger:
     def advance(self, t):
         self.net.advance(t)
 
+    def fetch(self, label, x):
+        return self.net._fetch(label, x)
+
     def on_start(self, node_id, t0, t1):
         # iteration span for the event trace (no-op without telemetry);
         # routes through the device ring under ObsConfig.device_spans
@@ -380,8 +401,8 @@ class _GossipLedger:
         # node was not already an approver of the row in its own replica —
         # the same predicate publish_at's crossing scan applies, so in the
         # ideal-wire limit issued == what survives the union exactly
-        rows = np.asarray(prepared.chosen_rows)
-        appr = np.asarray(dag_i.approvers)
+        rows, appr = self.net._fetch(
+            "approvals", (prepared.chosen_rows, dag_i.approvers))
         self.approvals_issued += int(
             sum(1 for r in rows if r >= 0 and not appr[r, node_id])
         )
@@ -427,7 +448,7 @@ class _GossipLedger:
         Algorithm-2 selection the same way the §VI.B credit extension
         does. The trailing slot covers publisher -1 (genesis). ``None``
         without a fault-state carry."""
-        credit = self.net.rejection_credit()
+        credit = self.net.rejection_credit(label="fault_bias")
         if credit is None:
             return None
         return jnp.log(jnp.concatenate([
@@ -435,13 +456,11 @@ class _GossipLedger:
         ]))
 
     def observe(self, done, t1, union):
-        self.divergence.append(
-            (done, float(t1), int(self.net.missing_rows(union).max()))
-        )
+        rows = self.net.missing_rows(union, label="observe")
+        self.divergence.append((done, float(t1), int(rows.max())))
         if self.net.bank_cfg is not None:
-            self.bank_lag.append(
-                (done, float(t1), int(self.net.missing_chunks().max()))
-            )
+            chunks = self.net.missing_chunks(label="observe")
+            self.bank_lag.append((done, float(t1), int(chunks.max())))
 
     def extras(self, union):
         out = {}
@@ -468,6 +487,8 @@ class _GossipLedger:
             "sync_rounds": self.net.rounds_run,
             "device_calls": self.net.device_calls,
             "dispatch_counts": dict(self.net.dispatch_counts),
+            "host_syncs": self.net.host_syncs,
+            "sync_counts": dict(self.net.sync_counts),
             "events_processed": self.net.events_processed,
             "synced_final": self.net.synced(),
             "missing_rows_final": self.net.missing_rows(union),

@@ -24,7 +24,12 @@ evaluated on device. Every state-advancing device call routes through the
 ``GossipNetwork._dispatch`` funnel — tick advance, event advance, the bank
 variants, converge, and commit accounting alike — so ``device_calls`` is
 the complete dispatch count benchmarks report (``dispatch_counts`` keeps
-the per-entry-point breakdown).
+the per-entry-point breakdown). Each dispatch runs inside a
+``jax.profiler.TraceAnnotation`` named ``repro.net.<label>``, and every
+blocking device->host read the driver loop makes goes through the
+``GossipNetwork._fetch`` funnel (``host_syncs``, ``sync_counts``, spans
+``repro.net.wait.<label>``): with a profiler recording, the host's time
+lands on the device trace's clock; without one, a span costs one check.
 
 Telemetry: constructed with ``obs_cfg=repro.obs.ObsConfig(...)``, the
 jitted loops thread device-resident collectors (metric accumulators + an
@@ -972,6 +977,8 @@ class GossipNetwork:
         self.rounds_run = 0          # ticks / event batches actually executed
         self.device_calls = 0        # jitted dispatches issued (_dispatch)
         self.dispatch_counts = {}    # per-entry-point dispatch breakdown
+        self.host_syncs = 0          # blocking device->host reads (_fetch)
+        self.sync_counts = {}        # per-label read breakdown
         self.events_processed = 0    # event batches fired (engine="events")
         if obs_cfg is not None:
             # telemetry carries (repro.obs): device-resident, threaded
@@ -1051,7 +1058,8 @@ class GossipNetwork:
         return self.replicas.bank
 
     def read(self, i) -> DagState:
-        return replica_lib.read_replica(self.replicas, i)
+        with jax.profiler.TraceAnnotation("repro.net.read"):
+            return replica_lib.read_replica(self.replicas, i)
 
     def write(self, i, dag: DagState, bank=None) -> None:
         self.replicas = replica_lib.write_replica(self.replicas, i, dag)
@@ -1069,11 +1077,12 @@ class GossipNetwork:
         chunks have not arrived are masked out (``bank.gate_view``) so
         Algorithm 2 cannot select or approve a payload-less transaction;
         without bank gossip this is exactly ``read`` (the PR-3 view)."""
-        dag = replica_lib.read_replica(self.replicas, i)
-        if self.bank_cfg is None:
-            return dag
-        gate = mesh_lib.replicated_jit(bank_lib.gate_view, self.mesh)
-        return gate(dag, self.replicas.bank_state.have[i], self._digest)
+        with jax.profiler.TraceAnnotation("repro.net.read"):
+            dag = replica_lib.read_replica(self.replicas, i)
+            if self.bank_cfg is None:
+                return dag
+            gate = mesh_lib.replicated_jit(bank_lib.gate_view, self.mesh)
+            return gate(dag, self.replicas.bank_state.have[i], self._digest)
 
     def bank_commit(self, node_id, slot, params) -> None:
         """Account a stage-4 commit in the transport state: the committer
@@ -1091,15 +1100,16 @@ class GossipNetwork:
             bank_state=bstate._replace(have=have)
         )
 
-    def missing_chunks(self) -> np.ndarray:
+    def missing_chunks(self, label: Optional[str] = None) -> np.ndarray:
         """(N,) referenced-but-unavailable chunks per node — the payload lag
-        behind row visibility (all zeros without bank gossip)."""
+        behind row visibility (all zeros without bank gossip). ``label``
+        routes the read through the ``_fetch`` funnel."""
         if self.bank_cfg is None:
             return np.zeros(self.topology.num_nodes, np.int32)
         count = mesh_lib.replicated_jit(bank_lib.missing_chunks, self.mesh,
                                         impl=self.bank_cfg.impl)
-        return np.asarray(count(
-            self.replicas.dags, self.replicas.bank_state, self._digest))
+        out = count(self.replicas.dags, self.replicas.bank_state, self._digest)
+        return np.asarray(out) if label is None else self._fetch(label, out)
 
     def bytes_sent(self) -> float:
         """Total payload bytes delivered so far (the Table-I traffic bill)."""
@@ -1120,14 +1130,15 @@ class GossipNetwork:
             return rows
         return rows and int(self.missing_chunks().max()) == 0
 
-    def missing_rows(self, union: Optional[DagState] = None) -> np.ndarray:
+    def missing_rows(self, union: Optional[DagState] = None,
+                     label: Optional[str] = None) -> np.ndarray:
         """(N,) rows each replica lacks vs the union view (0 = converged).
-        Pass a precomputed ``union()`` to avoid re-folding the replicas."""
+        Pass a precomputed ``union()`` to avoid re-folding the replicas;
+        ``label`` routes the read through the ``_fetch`` funnel."""
         if union is None:
             union = self.union()
-        return np.asarray(
-            replica_lib.missing_vs_union_jit(self.replicas.dags, union)
-        )
+        out = replica_lib.missing_vs_union_jit(self.replicas.dags, union)
+        return np.asarray(out) if label is None else self._fetch(label, out)
 
     # --- telemetry (only when constructed with obs_cfg) ---------------------
 
@@ -1248,15 +1259,17 @@ class GossipNetwork:
             self._fstate.rejects >= self.faults_cfg.quarantine_after
         )
 
-    def rejection_credit(self) -> Optional[np.ndarray]:
+    def rejection_credit(self, label: Optional[str] = None) -> Optional[np.ndarray]:
         """(N,) per-sender trust from cumulative digest rejections
         (``repro.core.anomaly.rejection_credit``) — 1.0 for clean senders,
         floored near 0 for quarantined spoofers. ``None`` without a
-        fault-state carry."""
+        fault-state carry. ``label`` routes the read through the ``_fetch``
+        funnel."""
         if self.faults_cfg is None or self._fstate is None:
             return None
         from repro.core import anomaly
-        return np.asarray(anomaly.rejection_credit(self._fstate.rejects))
+        out = anomaly.rejection_credit(self._fstate.rejects)
+        return np.asarray(out) if label is None else self._fetch(label, out)
 
     def tainted_in_views(self) -> np.ndarray:
         """(N,) corrupted chunks REFERENCED by rows visible in each node's
@@ -1316,17 +1329,28 @@ class GossipNetwork:
         variants, converge, commit accounting) routes through here, so
         ``device_calls`` — what the ``dispatch_batching`` bench reports —
         counts them all instead of the hand-instrumented subset it used to
-        see; ``dispatch_counts`` keeps the per-entry-point breakdown. With
-        telemetry on, the call is wrapped in a
-        ``jax.profiler.TraceAnnotation`` so device profiles name the
-        overlay's phases.
+        see; ``dispatch_counts`` keeps the per-entry-point breakdown. The
+        call runs inside a ``jax.profiler.TraceAnnotation`` named
+        ``repro.net.<label>``, so device profiles name the overlay's phases.
         """
         self.device_calls += 1
         self.dispatch_counts[label] = self.dispatch_counts.get(label, 0) + 1
-        if self.obs_cfg is not None and self.obs_cfg.annotate:
-            with jax.profiler.TraceAnnotation(f"repro.net.{label}"):
-                return fn(*args)
-        return fn(*args)
+        with jax.profiler.TraceAnnotation(f"repro.net.{label}"):
+            return fn(*args)
+
+    def _fetch(self, label: str, x):
+        """Read device values back to the host through the counting funnel.
+
+        ONE blocking device->host read of the pytree ``x`` (numpy leaves
+        out): every such read the DAG-FL driver loop makes routes through
+        here, so ``host_syncs`` counts the host's waits on the device and
+        ``sync_counts`` keeps the per-label breakdown. The wait runs inside
+        a ``jax.profiler.TraceAnnotation`` named ``repro.net.wait.<label>``.
+        """
+        self.host_syncs += 1
+        self.sync_counts[label] = self.sync_counts.get(label, 0) + 1
+        with jax.profiler.TraceAnnotation(f"repro.net.wait.{label}"):
+            return jax.device_get(x)
 
     def _run_ticks(self, ticks, part_active) -> None:
         """Execute a batch of sync ticks as ONE jitted device call."""
@@ -1484,9 +1508,10 @@ class GossipNetwork:
                 )
             self.replicas = self.replicas._replace(dags=dags)
         self._equeue = self._equeue._replace(time=qt, valid=qv)
-        self.tick += int(done)
-        self.rounds_run += int(done)
-        self.events_processed += int(done)
+        done = int(self._fetch("advance", done))
+        self.tick += done
+        self.rounds_run += done
+        self.events_processed += done
 
     def _advance_events_serve(self, t: float) -> None:
         """The event advance with the inference-serving slots live
@@ -1550,7 +1575,7 @@ class GossipNetwork:
         if self.obs_cfg is not None:
             self._metrics, self._ring = out["metrics"], out["ring"]
         self._equeue = self._equeue._replace(time=out["qt"], valid=out["qv"])
-        done = int(out["done"])
+        done = int(self._fetch("advance", out["done"]))
         self.tick += done
         self.rounds_run += done
         self.events_processed += done
@@ -1675,6 +1700,7 @@ class GossipNetwork:
                     self._metrics, self._ring, self._obs_period,
                 )
             self.replicas = self.replicas._replace(dags=dags)
+        tick, done, synced = self._fetch("converge", (tick, done, synced))
         self.tick = int(tick)
         self.rounds_run += int(done)
         return bool(synced)

@@ -77,9 +77,7 @@ class ObsConfig:
     ``trace_capacity`` — event records kept (``repro.obs.trace``);
     ``trace`` — record the PUBLISH/COMMIT/DELIVER/DRAIN/PARTITION event
     trace (metrics alone are cheaper when spans are not needed);
-    ``annotate`` — wrap each jitted dispatch in a
-    ``jax.profiler.TraceAnnotation`` so device profiles name the overlay's
-    phases; ``tau_max`` — the staleness threshold the sampled tip count
+    ``tau_max`` — the staleness threshold the sampled tip count
     uses (``dag.num_tips``; default = ``DagFLConfig.tau_max``);
     ``hist`` — when set, stream every in-loop latency sample into the
     fixed-bin histograms of ``repro.obs.hist`` (``MetricsState.hist``
@@ -92,7 +90,6 @@ class ObsConfig:
     series_capacity: int = 2048
     trace_capacity: int = 16384
     trace: bool = True
-    annotate: bool = True
     tau_max: float = 20.0
     hist: Optional[HistConfig] = None
     device_spans: bool = False
