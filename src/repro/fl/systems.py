@@ -489,6 +489,7 @@ class _GossipLedger:
             "dispatch_counts": dict(self.net.dispatch_counts),
             "host_syncs": self.net.host_syncs,
             "sync_counts": dict(self.net.sync_counts),
+            "read_calls": self.net.read_calls,
             "events_processed": self.net.events_processed,
             "synced_final": self.net.synced(),
             "missing_rows_final": self.net.missing_rows(union),

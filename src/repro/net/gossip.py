@@ -30,6 +30,8 @@ blocking device->host read the driver loop makes goes through the
 ``GossipNetwork._fetch`` funnel (``host_syncs``, ``sync_counts``, spans
 ``repro.net.wait.<label>``): with a profiler recording, the host's time
 lands on the device trace's clock; without one, a span costs one check.
+Replica reads (``read``, ``read_view``) are one compiled gather each
+(``replica.read_replica``), counted apart in ``read_calls``.
 
 Telemetry: constructed with ``obs_cfg=repro.obs.ObsConfig(...)``, the
 jitted loops thread device-resident collectors (metric accumulators + an
@@ -979,6 +981,7 @@ class GossipNetwork:
         self.dispatch_counts = {}    # per-entry-point dispatch breakdown
         self.host_syncs = 0          # blocking device->host reads (_fetch)
         self.sync_counts = {}        # per-label read breakdown
+        self.read_calls = 0          # compiled replica reads (read, read_view)
         self.events_processed = 0    # event batches fired (engine="events")
         if obs_cfg is not None:
             # telemetry carries (repro.obs): device-resident, threaded
@@ -1058,6 +1061,9 @@ class GossipNetwork:
         return self.replicas.bank
 
     def read(self, i) -> DagState:
+        """Node i's replica: one compiled gather, counted in ``read_calls``
+        (not ``device_calls``: a read advances no state)."""
+        self.read_calls += 1
         with jax.profiler.TraceAnnotation("repro.net.read"):
             return replica_lib.read_replica(self.replicas, i)
 
@@ -1077,6 +1083,7 @@ class GossipNetwork:
         chunks have not arrived are masked out (``bank.gate_view``) so
         Algorithm 2 cannot select or approve a payload-less transaction;
         without bank gossip this is exactly ``read`` (the PR-3 view)."""
+        self.read_calls += 1
         with jax.profiler.TraceAnnotation("repro.net.read"):
             dag = replica_lib.read_replica(self.replicas, i)
             if self.bank_cfg is None:

@@ -30,6 +30,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import dag as dag_lib
 from repro.core.dag import DagState
@@ -78,8 +79,22 @@ def init_replicas(
     return ReplicaSet(dags=dags, bank=bank)
 
 
+@jax.jit
+def _read_dags(dags: DagState, i) -> DagState:
+    return jax.tree_util.tree_map(lambda x: x[i], dags)
+
+
 def read_replica(rs: ReplicaSet, i) -> DagState:
-    return jax.tree_util.tree_map(lambda x: x[i], rs.dags)
+    """Replica ``i``'s ledger as one compiled gather over the stacked leaves.
+
+    ``i`` is a traced argument, so every node id shares one program per leaf
+    structure and shape; the slices are the eager ``x[i]``'s bits. Nothing is
+    donated (the commit writes the replicas afterwards) and nothing waits on
+    the device.
+    """
+    if not isinstance(i, jax.Array):
+        i = np.int32(i)   # one trace for Python and numpy ids alike
+    return _read_dags(rs.dags, i)
 
 
 @functools.partial(jax.jit, donate_argnums=0)

@@ -84,6 +84,25 @@ def test_zero_iteration_run_returns_empty_curve(runner):
     assert len(res.extras["behaviors"]) == n
 
 
+@pytest.mark.parametrize("engine,device_calls", [("events", 20), ("ticks", 11)])
+def test_gossip_reads_two_replicas_per_iteration(engine, device_calls):
+    """Each committed iteration reads its node's replica twice (the view at
+    start, the replica at commit), counted in ``read_calls`` and not in
+    ``device_calls``, which keeps the count it had with eager reads."""
+    n = 6
+    task, nodes, gval, _ = make_cnn_setup(num_nodes=n, seed=0)
+    res = run_dagfl_gossip(
+        task, nodes, default_dagfl_config(num_nodes=n),
+        SimConfig(iterations=10, eval_every=5, seed=0), gval,
+        topology=topo.full(n, link_latency=0.5),
+        gossip=GossipConfig(sync_period=1.0, seed=0), engine=engine,
+    )
+    committed = int(np.sum(res.extras["published"][:-1]))
+    assert committed == 10
+    assert res.extras["read_calls"] == 2 * committed
+    assert res.extras["device_calls"] == device_calls
+
+
 def test_gossip_stale_overlay_diverges_and_reports_metrics():
     n, dcfg = 12, default_dagfl_config(num_nodes=12)
     sim = SimConfig(iterations=40, eval_every=10, seed=0)

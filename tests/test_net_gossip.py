@@ -60,6 +60,46 @@ def test_replica_roundtrip_and_shared_start():
     assert int(net.read(0).count) == 1          # others unaffected until sync
 
 
+@pytest.mark.parametrize("pick", ["first", "second", "last"])
+def test_compiled_read_matches_eager_slices(pick):
+    """The compiled gather returns, bitwise for every leaf, what slicing
+    each stacked leaf eagerly returns, on replicas whose rows differ."""
+    r = 6
+    net = make_net(topo.ring(r))
+    publish_on(net, 0, seq=1, t=0.5)
+    publish_on(net, 1, seq=2, t=0.7, approvals=jnp.asarray([0, dag_lib.NO_TX], jnp.int32))
+    publish_on(net, r - 1, seq=3, t=0.9, approvals=jnp.asarray([0, 1], jnp.int32))
+    assert not net.synced()
+    i = {"first": 0, "second": 1, "last": r - 1}[pick]
+    got = net.read(i)
+    want = jax.tree_util.tree_map(lambda x: x[i], net.replicas.dags)
+    for name in dag_lib.DagState._fields:
+        a, b = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_reading_every_node_traces_the_gather_once():
+    """Node ids are traced arguments: 16 ids, Python or numpy, share one
+    program (a capacity no other test uses makes the cache delta exact)."""
+    n = 16
+    d = dag_lib.publish(
+        dag_lib.empty_dag(24, 3, n + 1), jnp.asarray(n, jnp.int32),
+        jnp.float32(0.0), jnp.full((3,), dag_lib.NO_TX, jnp.int32),
+        jnp.float32(0.5), jnp.float32(0.0), jnp.asarray(0, jnp.int32),
+    )
+    net = gossip_lib.GossipNetwork(
+        d, bank=jnp.zeros((24, 4)), top=topo.ring(n),
+        cfg=gossip_lib.GossipConfig(sync_period=1.0, seed=0),
+    )
+    before = replica_lib._read_dags._cache_size()
+    for i in range(n):
+        net.read(i if i % 2 else np.int64(i))
+    assert replica_lib._read_dags._cache_size() - before == 1
+    assert net.read_calls == n
+    assert net.device_calls == 0        # a read advances no state
+
+
 @pytest.mark.parametrize("impl", IMPLS)
 def test_ring_propagates_one_hop_per_tick(impl):
     net = make_net(topo.ring(6), impl=impl)

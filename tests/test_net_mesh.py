@@ -242,6 +242,26 @@ def test_property_mesh_round_equals_fused(seed, overlay, window, split):
     assert_dags_equal(a.replicas.dags, b.replicas.dags, msg="converge:")
 
 
+@multidevice
+@pytest.mark.parametrize("i", [0, 1, 15])
+def test_compiled_read_of_sharded_replicas_matches_eager_slices(i):
+    """The compiled gather over receiver-sharded replicas returns the eager
+    slice's bits, and its result feeds the donated write."""
+    rng = np.random.default_rng(7)
+    mesh = mesh_lib.make_gossip_mesh(nodes=8)
+    dags = mesh_lib.shard_replicas(random_stacked(rng, 16), mesh)
+    rs = replica_lib.ReplicaSet(dags=dags, bank=jnp.zeros((CAP, 4)))
+    got = replica_lib.read_replica(rs, i)
+    assert_dags_equal(got, jax.tree_util.tree_map(lambda x: x[i], dags),
+                      msg=f"read {i}:")
+    want = jax.tree_util.tree_map(np.asarray, dags)
+    rs = replica_lib.write_replica(rs, (i + 1) % 16, got)
+    assert_dags_equal(
+        replica_lib.read_replica(rs, (i + 1) % 16),
+        jax.tree_util.tree_map(lambda x: x[i], want), msg=f"write {i}:",
+    )
+
+
 # ---------------------------------------------------------------------------
 # e2e sim + single-device lane coverage (subprocess pins its own XLA flags)
 # ---------------------------------------------------------------------------
@@ -295,6 +315,11 @@ def test_sharded_round_equivalence_in_subprocess():
                 jnp.float32(0.1), jnp.full((K,), dag_lib.NO_TX, jnp.int32),
                 jnp.float32(0.5), jnp.float32(0.0), jnp.asarray(1, jnp.int32))
             n_.write(3, dd)
+        for i in (0, 3, 15):      # the compiled read of sharded replicas
+            for f in dag_lib.DagState._fields:
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(b.read(i), f)),
+                    np.asarray(getattr(b.replicas.dags, f)[i]), err_msg=f)
         a.advance(4.0); b.advance(4.0)
         assert a.converge(at_time=50.0) == b.converge(at_time=50.0)
         for f in dag_lib.DagState._fields:

@@ -133,6 +133,14 @@ def test_host_syncs_match_the_loop_and_the_wait_spans(traced):
     assert set(ex["sync_counts"]) == {"advance", "approvals", "observe", "check", "snapshot"}
 
 
+def test_read_spans_match_read_calls(traced):
+    """One ``repro.net.read`` span per compiled replica read, two per
+    committed iteration (the start's view, the commit's replica)."""
+    _, res, spans = traced
+    reads = sum(1 for n, *_ in spans if n == "repro.net.read")
+    assert reads == res.extras["read_calls"] == 2 * ITERS
+
+
 def test_profiler_leaves_the_trajectory_unchanged(traced):
     untraced, res, _ = traced
     np.testing.assert_array_equal(untraced.accs, res.accs)
