@@ -25,6 +25,19 @@ ACT_KINDS = ("swiglu", "geglu", "gelu")
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2's config states it
+    (``rope_scaling`` with ``type: yarn``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # one of FAMILIES
@@ -42,6 +55,7 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScaling] = None   # None => plain rope
 
     # --- MLA (DeepSeek-V2 style) ---
     kv_lora_rank: int = 0
@@ -52,6 +66,7 @@ class ModelConfig:
     # --- norms / MLP ---
     norm: str = "rmsnorm"
     act: str = "swiglu"
+    rms_norm_eps: float = 1e-6
 
     # --- MoE ---
     num_experts: int = 0             # routed experts; 0 => dense MLP
@@ -60,7 +75,15 @@ class ModelConfig:
     moe_d_ff: int = 0                # 0 => d_ff (per-expert hidden)
     router_aux_loss: float = 0.01
     first_dense_layers: int = 0      # DeepSeek keeps layer 0 dense
-    moe_impl: str = "sorted"         # "sorted" (prod) | "dense" (oracle)
+    moe_impl: str = "sorted"         # "sorted" | "dense" (oracle) | "ragged" (dropless)
+    # gating: softmax over all experts, then top-k; True renormalises the k
+    # gates to sum to 1 (DeepSeek-V2's ``norm_topk_prob``), False keeps them
+    norm_topk_prob: bool = True
+    # the experts this device holds (expert parallelism): routed experts
+    # [expert_offset, expert_offset + experts_held) of the num_experts the
+    # router scores; 0 => all of them. Only the "ragged" impl honours it.
+    expert_offset: int = 0
+    experts_held: int = 0
 
     # --- SSM / RWKV ---
     ssm_state: int = 0               # Mamba2 state size per head
@@ -98,6 +121,9 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
     def sub_quadratic(self) -> bool:
         """True when a 500k-token decode is admissible (bounded state)."""
         return self.attention in ("none", "sliding_window") or self.family in (
@@ -129,6 +155,8 @@ class ModelConfig:
             rope_head_dim=min(self.rope_head_dim, 32) if self.kv_lora_rank else self.rope_head_dim,
             v_head_dim=64 if self.v_head_dim else 0,
             num_experts=min(self.num_experts, 4) if self.num_experts else 0,
+            expert_offset=0,
+            experts_held=min(self.experts_held, 4),
             num_shared_experts=min(self.num_shared_experts, 1),
             experts_per_token=min(self.experts_per_token, 2) if self.experts_per_token else 0,
             first_dense_layers=min(self.first_dense_layers, 1),
@@ -180,7 +208,7 @@ class ModelConfig:
             n_gate = 2 if self.act in ("swiglu", "geglu") else 1
             if self.is_moe():
                 eff = self.moe_d_ff or self.d_ff
-                moe = self.num_experts * (n_gate + 1) * d * eff
+                moe = self.resolved_experts_held() * (n_gate + 1) * d * eff
                 moe += self.num_shared_experts * (n_gate + 1) * d * eff
                 moe += d * self.num_experts  # router
                 per_layer += moe
@@ -203,6 +231,7 @@ class ModelConfig:
             self,
             num_experts=self.experts_per_token,
             num_shared_experts=self.num_shared_experts,
+            experts_held=0,
         )
         return dense_like.param_count()
 

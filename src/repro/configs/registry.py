@@ -7,6 +7,7 @@ from repro.configs.base import ModelConfig, SHAPES, ShapeSpec
 
 from repro.configs.olmo_1b import CONFIG as _olmo_1b
 from repro.configs.deepseek_v2_236b import CONFIG as _deepseek_v2
+from repro.configs.deepseek_v2_lite import CONFIG as _deepseek_v2_lite
 from repro.configs.gemma_2b import CONFIG as _gemma_2b
 from repro.configs.qwen3_0_6b import CONFIG as _qwen3
 from repro.configs.kimi_k2_1t_a32b import CONFIG as _kimi_k2
@@ -21,6 +22,7 @@ ARCHS: Dict[str, ModelConfig] = {
     for cfg in (
         _olmo_1b,
         _deepseek_v2,
+        _deepseek_v2_lite,
         _gemma_2b,
         _qwen3,
         _kimi_k2,

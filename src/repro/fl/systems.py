@@ -133,11 +133,21 @@ def _identity_train(params, batch, key):
 
 
 def _build_stage_jits(dcfg, task, weighted):
-    prep_normal, commit_fn = make_dagfl_stages(
-        dcfg, task.eval_fn, make_epoch_train(task), weighted
-    )
-    prep_lazy, _ = make_dagfl_stages(dcfg, task.eval_fn, _identity_train, weighted)
-    return jax.jit(prep_normal), jax.jit(prep_lazy), commit_fn
+    # a task's frozen device state (``task.frozen``: ``LoRALMTask``'s base,
+    # none for the paper's tasks) is the jitted prepare's first argument, so
+    # the program reads it in place instead of baking it in as constants
+    frozen = getattr(task, "frozen", ())
+    bind = getattr(task, "bind", lambda state: task)
+
+    def jitted(make_train):
+        def prepare(state, dag, bank, now, key, train_batch, val_batch, node_bias=None):
+            t = bind(state)
+            stage, _ = make_dagfl_stages(dcfg, t.eval_fn, make_train(t), weighted)
+            return stage(dag, bank, now, key, train_batch, val_batch, node_bias)
+
+        return functools.partial(jax.jit(prepare), frozen)
+
+    return jitted(make_epoch_train), jitted(lambda t: _identity_train), commit_prepared
 
 
 _stage_jits_cached = functools.lru_cache(maxsize=None)(_build_stage_jits)
@@ -148,7 +158,8 @@ def _stage_jits(dcfg, task, weighted):
 
     ``DagFLConfig`` and the paper tasks are frozen dataclasses, so sweeps
     that rebuild an equal task per run hit the cache and stop re-tracing
-    stages 1-3; an unhashable ad-hoc task just falls back to a fresh trace.
+    stages 1-3 (``LoRALMTask`` hashes by identity: one task, one trace); an
+    unhashable ad-hoc task just falls back to a fresh trace.
     """
     try:
         return _stage_jits_cached(dcfg, task, weighted)

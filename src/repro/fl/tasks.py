@@ -5,22 +5,33 @@
 * LSTM: 8-dim char embedding -> 2x LSTM(256) -> softmax per char
   (the stacked character LSTM, lr 0.3 in the paper).
 
+Beside them, ``LoRALMTask``: federated low-rank adaptation of a frozen
+language model (a registry ``ModelConfig``), the payload being the adapters.
+
 Each task exposes the interface DAG-FL core consumes:
   init(key) -> params
   eval_fn(params, batch) -> accuracy in [0,1]
   train_fn(params, batch, key) -> (params, metrics)   # one epoch/minibatch
 Sizes are configurable so benches can run a scaled-down variant on CPU.
+A task with frozen device state besides its params also exposes
+  frozen -> pytree of arrays;  bind(frozen) -> the task computing with it
+so that the jitted stages take that state as an argument (``fl/systems.py``).
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 from dataclasses import dataclass
-from typing import Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.configs.base import ModelConfig
+from repro.models import lora as lora_lib
 from repro.models.layers import softmax_xent
+from repro.models.transformer import Model
 
 
 # ---------------------------------------------------------------------------
@@ -162,6 +173,98 @@ class LSTMTask:
         logits = self.logits(params, tokens)[:, :-1]
         pred = jnp.argmax(logits, -1)
         return jnp.mean((pred == tokens[:, 1:]).astype(jnp.float32))
+
+    def train_fn(self, params, batch, key):
+        loss, grads = jax.value_and_grad(self.loss)(params, batch)
+        params = jax.tree_util.tree_map(
+            lambda p, g: p - self.learning_rate * g, params, grads
+        )
+        return params, {"loss": loss}
+
+
+# ---------------------------------------------------------------------------
+# LoRA fine-tuning of a frozen language model
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _base_shapes(cfg: ModelConfig):
+    return jax.eval_shape(Model(cfg).init, jax.random.PRNGKey(0))
+
+
+@functools.lru_cache(maxsize=None)
+def frozen_base(cfg: ModelConfig, base_seed: int):
+    """The frozen base weights of ``cfg``, built once per process on the
+    default device: ``lora.random_base`` from ``PRNGKey(base_seed)`` (one
+    key per leaf by its path via ``fold_in``, fan-in scaled), in one jitted
+    call that takes the key as an argument (a key the compiler sees as a
+    constant lets it fold the draws into the executable)."""
+    draw = jax.jit(functools.partial(lora_lib.random_base, shapes=_base_shapes(cfg)))
+    base = draw(jax.random.PRNGKey(base_seed))
+    if any(isinstance(x, jax.core.Tracer) for x in jax.tree_util.tree_leaves(base)):
+        raise RuntimeError("build the frozen base outside a trace (task.frozen)")
+    return base
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _lm_accuracy(model: Model, lora: lora_lib.LoRAConfig, base, adapters, tokens):
+    logits, _ = model.forward(lora_lib.merge(base, adapters, lora), tokens)
+    pred = jnp.argmax(logits[:, :-1], -1)
+    return jnp.mean((pred == tokens[:, 1:]).astype(jnp.float32))
+
+
+@dataclass(frozen=True, eq=False)
+class LoRALMTask:
+    """Federated instruction tuning in the FedIT / OpenFedLLM manner
+    (arXiv:2305.05644, arXiv:2402.06954): each node trains low-rank adapters
+    (``models/lora.py``) over a frozen base shared by all nodes, and the
+    DAG-FL transaction is the adapters alone.
+
+    * ``init(key)`` returns adapters (``a`` seeded, ``b`` zero); the bank
+      stores them.
+    * ``eval_fn`` is next-token top-1 accuracy over ``batch["tokens"]``.
+    * ``train_fn`` is one plain SGD step on the adapters. The loss is the
+      next-token cross-entropy alone: the router is frozen with the base, so
+      its load-balance auxiliary loss has nothing to train and is left out.
+
+    The base (``frozen``) lives on the device, built once per process from
+    ``base_seed`` (``frozen_base``); ``bind`` gives a task that computes
+    with a given base, which is how the jitted stages receive it as an
+    argument instead of a constant baked into the program. The task hashes
+    by identity, so a run's episodes share one trace of the stages.
+    """
+
+    cfg: ModelConfig
+    base_seed: int = 0
+    learning_rate: float = 0.05
+    lora: lora_lib.LoRAConfig = lora_lib.LoRAConfig()
+    base: Optional[Any] = None          # None: frozen_base(cfg, base_seed)
+
+    @property
+    def model(self) -> Model:
+        return Model(self.cfg, remat=False)
+
+    @property
+    def frozen(self):
+        return self.base if self.base is not None else frozen_base(self.cfg, self.base_seed)
+
+    def bind(self, base) -> "LoRALMTask":
+        return dataclasses.replace(self, base=base)
+
+    def init(self, key) -> Dict:
+        return lora_lib.init_adapters(key, _base_shapes(self.cfg), self.lora)
+
+    def logits(self, adapters, tokens):
+        logits, _ = self.model.forward(lora_lib.merge(self.frozen, adapters, self.lora),
+                                       tokens)
+        return logits
+
+    def loss(self, adapters, batch):
+        tokens = batch["tokens"]
+        return softmax_xent(self.logits(adapters, tokens)[:, :-1], tokens[:, 1:])
+
+    def eval_fn(self, params, batch) -> jnp.ndarray:
+        return _lm_accuracy(self.model, self.lora, self.frozen, params, batch["tokens"])
 
     def train_fn(self, params, batch, key):
         loss, grads = jax.value_and_grad(self.loss)(params, batch)

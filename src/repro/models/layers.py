@@ -101,13 +101,55 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature term, 0.1 * mscale * ln(factor) + 1."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_dim(rotations: float, dim: int, theta: float, max_pos: int) -> float:
+    return dim * math.log(max_pos / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+
+def yarn_freqs(head_dim: int, theta: float, scaling) -> jnp.ndarray:
+    """YaRN inverse frequencies (arXiv:2309.00071), as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` computes them: dimensions that turn
+    more than ``beta_fast`` times over the original context keep their
+    frequency, those that turn fewer than ``beta_slow`` times are divided by
+    ``factor``, and a linear ramp blends the ones between."""
+    orig = scaling.original_max_position_embeddings
+    low = max(math.floor(_yarn_correction_dim(scaling.beta_fast, head_dim, theta, orig)), 0)
+    high = min(math.ceil(_yarn_correction_dim(scaling.beta_slow, head_dim, theta, orig)),
+               head_dim - 1)
+    if low == high:
+        high += 0.001
+    pos = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    extra = 1.0 / (theta ** pos)
+    inter = 1.0 / (scaling.factor * theta ** pos)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling=None) -> jnp.ndarray:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).
+
+    ``scaling`` (a ``YarnScaling``) switches to YaRN frequencies, with cos
+    and sin scaled by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if scaling is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
+    else:
+        freqs = yarn_freqs(hd, theta, scaling)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     sin = jnp.sin(ang)[..., :, None, :]                 # (..., S, 1, hd/2)
     cos = jnp.cos(ang)[..., :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            sin, cos = sin * m, cos * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, dense_init, norm_apply, norm_init
+from repro.models import lora as lora_lib
+from repro.models.layers import apply_rope, dense_init, norm_apply, norm_init, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -47,27 +48,58 @@ def mla_init(key, cfg: ModelConfig, dtype=None) -> dict:
     return params
 
 
+def _dense(params: dict, name: str, x):
+    """``x @ params[name]``, plus its low-rank adapter where the block
+    carries one (``models/lora.py``)."""
+    y = x @ params[name]
+    ad = params.get("lora", {}).get(name)
+    if ad is not None:
+        y = y + lora_lib.delta(ad, x)
+    return y
+
+
+def _weight(params: dict, name: str):
+    """``params[name]`` with its adapter folded in (the absorbed decode
+    reshapes the weight itself)."""
+    w = params[name]
+    ad = params.get("lora", {}).get(name)
+    if ad is not None:
+        w = w + (ad.a @ ad.b) * ad.scale
+    return w
+
+
+def softmax_scale(cfg: ModelConfig):
+    """1/sqrt(qk head dim), times mscale(factor, mscale_all_dim)^2 under YaRN
+    (DeepSeek-V2's ``softmax_scale``)."""
+    scale = 1.0 / jnp.sqrt(jnp.float32(cfg.resolved_head_dim() + cfg.rope_head_dim))
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        m = yarn_mscale(ys.factor, ys.mscale_all_dim)
+        scale = scale * (m * m)
+    return scale
+
+
 def _queries(cfg: ModelConfig, params: dict, x, positions):
     B, S, _ = x.shape
     H = cfg.num_heads
     p_dim, rd = cfg.resolved_head_dim(), cfg.rope_head_dim
     if cfg.q_lora_rank:
-        cq = norm_apply("rmsnorm", params["q_norm"], x @ params["wq_a"])
+        cq = norm_apply("rmsnorm", params["q_norm"], x @ params["wq_a"], cfg.rms_norm_eps)
         q = cq @ params["wq_b"]
     else:
-        q = x @ params["wq"]
+        q = _dense(params, "wq", x)
     q = q.reshape(B, S, H, p_dim + rd)
     q_nope, q_rope = q[..., :p_dim], q[..., p_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope
 
 
 def _latent(cfg: ModelConfig, params: dict, x, positions):
     r, rd = cfg.kv_lora_rank, cfg.rope_head_dim
-    kv_a = x @ params["wkv_a"]
-    c_kv = norm_apply("rmsnorm", params["kv_norm"], kv_a[..., :r])
+    kv_a = _dense(params, "wkv_a", x)
+    c_kv = norm_apply("rmsnorm", params["kv_norm"], kv_a[..., :r], cfg.rms_norm_eps)
     k_rope = kv_a[..., r:][..., None, :]                  # single shared head
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)[..., 0, :]
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta, cfg.rope_scaling)[..., 0, :]
     return c_kv, k_rope
 
 
@@ -80,18 +112,23 @@ def mla_forward(
     cache_len: int = 0,
 ) -> Tuple[jnp.ndarray, Optional[MLACache]]:
     B, S, _ = x.shape
-    H = cfg.num_heads
-    p_dim, v_dim, rd = cfg.resolved_head_dim(), cfg.resolved_v_head_dim(), cfg.rope_head_dim
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    with jax.named_scope("lm/mla"):
+        return _mla_forward(cfg, params, x, positions, return_cache, cache_len)
 
+
+def _mla_forward(cfg, params, x, positions, return_cache, cache_len):
+    B, S, _ = x.shape
+    H = cfg.num_heads
+    p_dim, v_dim, rd = cfg.resolved_head_dim(), cfg.resolved_v_head_dim(), cfg.rope_head_dim
     q_nope, q_rope = _queries(cfg, params, x, positions)
     c_kv, k_rope = _latent(cfg, params, x, positions)
 
-    kv = (c_kv @ params["wkv_b"]).reshape(B, S, H, p_dim + v_dim)
+    kv = _dense(params, "wkv_b", c_kv).reshape(B, S, H, p_dim + v_dim)
     k_nope, v = kv[..., :p_dim], kv[..., p_dim:]
 
-    scale = 1.0 / jnp.sqrt(jnp.float32(p_dim + rd))
+    scale = softmax_scale(cfg)
 
     def block_attn(q_nope_b, q_rope_b, offset):
         """One query block vs the full keys: scores O(bq * S)."""
@@ -123,7 +160,7 @@ def mla_forward(
 
         _, outs = jax.lax.scan(body, None, (jnp.arange(nb), qn, qr))
         out = jnp.moveaxis(outs, 0, 1).reshape(B, nb * BQ, H, v_dim)[:, :S]
-    out = out.reshape(B, S, H * v_dim) @ params["wo"]
+    out = _dense(params, "wo", out.reshape(B, S, H * v_dim))
 
     cache = None
     if return_cache:
@@ -163,12 +200,12 @@ def mla_decode_step(
     c_kv = jax.lax.dynamic_update_slice_in_dim(cache.c_kv, c_new, slot, axis=1)
     k_rope = jax.lax.dynamic_update_slice_in_dim(cache.k_rope, kr_new, slot, axis=1)
 
-    w_b = params["wkv_b"].reshape(r, H, p_dim + v_dim)
+    w_b = _weight(params, "wkv_b").reshape(r, H, p_dim + v_dim)
     w_uk, w_uv = w_b[..., :p_dim], w_b[..., p_dim:]
 
     # absorbed: q_lat[b,h,r] = sum_p q_nope[b,h,p] * w_uk[r,h,p]
     q_lat = jnp.einsum("bqhp,rhp->bqhr", q_nope, w_uk)
-    scale = 1.0 / jnp.sqrt(jnp.float32(p_dim + rd))
+    scale = softmax_scale(cfg)
     scores = (
         jnp.einsum("bqhr,bsr->bhqs", q_lat, c_kv)
         + jnp.einsum("bqhp,bsp->bhqs", q_rope, k_rope)
@@ -179,5 +216,5 @@ def mla_decode_step(
 
     ctx_lat = jnp.einsum("bhqs,bsr->bqhr", probs, c_kv)
     out = jnp.einsum("bqhr,rhv->bqhv", ctx_lat, w_uv)
-    out = out.reshape(B, 1, H * v_dim) @ params["wo"]
+    out = _dense(params, "wo", out.reshape(B, 1, H * v_dim))
     return out, MLACache(c_kv, k_rope, pos + 1)

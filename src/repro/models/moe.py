@@ -1,6 +1,6 @@
 """Mixture-of-Experts block: shared + routed experts, top-k routing.
 
-Two dispatch implementations:
+Three dispatch implementations:
 
 * ``dense``  — every expert runs on every token, gates mask the combine.
                O(T*E*d*ff) compute: only sane at smoke scale (E <= 4) and as
@@ -11,17 +11,28 @@ Two dispatch implementations:
                This is the production path; the distribution layer shards the
                expert dimension over the ``model`` mesh axis (expert
                parallelism) so the scatter/gather becomes the MoE all-to-all.
+* ``ragged`` — dropless, for the experts this device holds
+               (``cfg.expert_offset``, ``cfg.experts_held``): the router
+               scores all ``num_experts``, the (token, expert) pairs routed to
+               a held expert are sorted by expert and go through one grouped
+               matmul per projection (``jax.lax.ragged_dot``), whose work is
+               that of those pairs alone. No pair is dropped. The result is
+               this device's share: what the absent experts add comes from
+               the devices that hold them.
 
-Router: softmax-after-top-k (DeepSeek style), plus the switch-transformer
-load-balance auxiliary loss.
+Router: softmax over all experts, top-k, and with ``cfg.norm_topk_prob`` the k
+gates renormalised (DeepSeek-V2's ``MoEGate``, greedy top-k), plus the
+switch-transformer load-balance auxiliary loss.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init, mlp_apply, mlp_init
@@ -31,7 +42,7 @@ def moe_init(key, cfg: ModelConfig, dtype=None) -> dict:
     dtype = dtype or jnp.dtype(cfg.dtype)
     d = cfg.d_model
     ff = cfg.moe_d_ff or cfg.d_ff
-    E = cfg.num_experts
+    E = cfg.resolved_experts_held()
     kr, ki, kg, ko, ks = jax.random.split(key, 5)
     scale = 1.0 / math.sqrt(d)
 
@@ -40,7 +51,7 @@ def moe_init(key, cfg: ModelConfig, dtype=None) -> dict:
         return w.astype(dtype)
 
     p = {
-        "router": dense_init(kr, d, E, dtype),
+        "router": dense_init(kr, d, cfg.num_experts, dtype),
         "wi": stack(ki, d, ff),
         "wo": stack(ko, ff, d) * math.sqrt(d) / math.sqrt(ff),
     }
@@ -70,11 +81,15 @@ def route(cfg: ModelConfig, params: dict, x: jnp.ndarray):
     """x: (T, d) -> gates (T, k), expert ids (T, k), aux loss ()."""
     logits = (x @ params["router"]).astype(jnp.float32)     # (T, E)
     k = cfg.experts_per_token
-    top_logits, top_idx = jax.lax.top_k(logits, k)
-    gates = jax.nn.softmax(top_logits, axis=-1)             # normalize over k
+    probs = jax.nn.softmax(logits, axis=-1)
+    if cfg.norm_topk_prob:
+        # softmax over all, top-k, renormalised == softmax over the top-k logits
+        top_logits, top_idx = jax.lax.top_k(logits, k)
+        gates = jax.nn.softmax(top_logits, axis=-1)
+    else:
+        gates, top_idx = jax.lax.top_k(probs, k)
 
     # switch load-balance aux: E * sum_e load_e * importance_e
-    probs = jax.nn.softmax(logits, axis=-1)
     importance = jnp.mean(probs, axis=0)                    # (E,)
     load = jnp.mean(
         jnp.sum(jax.nn.one_hot(top_idx, cfg.num_experts, dtype=jnp.float32), axis=1),
@@ -135,6 +150,115 @@ def moe_apply_sorted(cfg: ModelConfig, params: dict, x: jnp.ndarray, capacity_fa
     return y, aux
 
 
+def _held_ffn_plain(cfg: ModelConfig, x, top_idx, gates, w):
+    """This device's part of the routed experts' output. x (T, d); top_idx,
+    gates (T, k) over all ``num_experts``; w the held experts' (H, ., .)
+    projections (``wi``, ``wg`` when gated, ``wo``)."""
+    T, k = top_idx.shape
+    H = cfg.resolved_experts_held()
+    local = top_idx.reshape(-1) - cfg.expert_offset
+    held = (local >= 0) & (local < H)
+    group = jnp.where(held, local, H)                       # H: held elsewhere
+    order = jnp.argsort(group, stable=True)                 # held pairs first, by expert
+    tok = order // k
+    sizes = jnp.bincount(group, length=H + 1)[:H].astype(jnp.int32)
+    live = held[order][:, None]
+    # rows past sum(sizes) belong to no held expert: ragged_dot leaves them
+    # out of the product, and on a TPU leaves whatever memory held in its
+    # output there. Every product's output is masked, and so is its input,
+    # whose cotangent is the backward product's output.
+    rows = jnp.where(live, x[tok], 0.0)                     # (T*k, d)
+
+    def grouped(a, wt):
+        return jnp.where(live, jax.lax.ragged_dot(a, wt, sizes), 0.0)
+
+    h = grouped(rows, w[0])
+    if cfg.act == "swiglu":
+        h = jax.nn.silu(grouped(rows, w[1])) * h
+    elif cfg.act == "geglu":
+        h = jax.nn.gelu(grouped(rows, w[1]), approximate=True) * h
+    else:
+        h = jax.nn.gelu(h, approximate=True)
+    out = grouped(h, w[-1]) * gates.reshape(-1)[order].astype(x.dtype)[:, None]
+    return jax.ops.segment_sum(out, tok, num_segments=T)
+
+
+def _is_zero(t) -> bool:
+    return isinstance(t, jax.custom_derivatives.SymbolicZero)
+
+
+@functools.lru_cache(maxsize=None)
+def _held_ffn(cfg: ModelConfig):
+    """``_held_ffn_plain`` for ``cfg``, made batchable and differentiable.
+
+    ``jax.lax.ragged_dot`` has no batching rule for weights shared across a
+    batch, which is how DAG-FL validation evaluates several adapted models
+    over one frozen base. Routing is per token, so a batch of token sets is
+    one larger token set: the batching rule flattens the batch into the
+    tokens and makes one grouped product. Differentiation goes through the
+    plain function, in the inputs whose tangents are not symbolic zeros
+    only, so a frozen expert's weights cost no tangent product.
+    """
+    plain = functools.partial(_held_ffn_plain, cfg)
+
+    @custom_vmap
+    def batched(x, top_idx, gates, w):
+        return plain(x, top_idx, gates, w)
+
+    @batched.def_vmap
+    def _vmap(axis_size, in_batched, x, top_idx, gates, w):
+        def lead(a, b):
+            return a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+
+        if any(jax.tree_util.tree_leaves(in_batched[3])):
+            raise NotImplementedError("held experts batched over their weights")
+        x, top_idx, gates = (lead(a, b) for a, b in zip((x, top_idx, gates), in_batched))
+
+        def flat(a):
+            return a.reshape((-1,) + a.shape[2:])
+
+        y = batched(flat(x), flat(top_idx), flat(gates), w)
+        return y.reshape(x.shape), True
+
+    @jax.custom_jvp
+    def ffn(x, top_idx, gates, w):
+        return batched(x, top_idx, gates, w)
+
+    def _jvp(primals, tangents):
+        x, top_idx, gates, w = primals
+        vals = {"x": x, "gates": gates, "w": w}
+        tans = dict(zip(("x", "gates", "w"), (tangents[0], tangents[2], tangents[3])))
+        live = [n for n, t in tans.items()
+                if not all(map(_is_zero, jax.tree_util.tree_leaves(t, is_leaf=_is_zero)))]
+
+        def f(*args):
+            kw = dict(vals, **dict(zip(live, args)))
+            return plain(kw["x"], top_idx, kw["gates"], kw["w"])
+
+        def dense(t, p):
+            return jnp.zeros_like(p) if _is_zero(t) else t
+
+        if not live:
+            y = f()
+            return y, jnp.zeros_like(y)
+        return jax.jvp(f, tuple(vals[n] for n in live),
+                       tuple(jax.tree_util.tree_map(dense, tans[n], vals[n], is_leaf=_is_zero)
+                             for n in live))
+
+    ffn.defjvp(_jvp, symbolic_zeros=True)
+    return ffn
+
+
+def moe_apply_ragged(cfg: ModelConfig, params: dict, x: jnp.ndarray):
+    """Dropless held-expert path (module docstring). x (T, d)."""
+    with jax.named_scope("lm/moe/route"):
+        gates, top_idx, aux = route(cfg, params, x)
+    with jax.named_scope("lm/moe/experts"):
+        w = tuple(params[n] for n in ("wi", "wg", "wo") if n in params)
+        y = _held_ffn(cfg)(x, top_idx, gates, w)
+    return y, aux
+
+
 MOE_BLOCK_TOKENS = 32768
 
 
@@ -188,6 +312,8 @@ def moe_apply(cfg: ModelConfig, params: dict, x: jnp.ndarray, impl: str = "sorte
         y, aux = moe_apply_dense(cfg, params, flat)
     elif impl == "blocked":
         y, aux = moe_apply_blocked(cfg, params, flat)
+    elif impl == "ragged":
+        y, aux = moe_apply_ragged(cfg, params, flat)
     else:
         y, aux = moe_apply_sorted(cfg, params, flat)
     if cfg.num_shared_experts:
@@ -196,5 +322,6 @@ def moe_apply(cfg: ModelConfig, params: dict, x: jnp.ndarray, impl: str = "sorte
         shared_cfg = dataclasses.replace(
             cfg, d_ff=cfg.num_shared_experts * (cfg.moe_d_ff or cfg.d_ff)
         )
-        y = y + mlp_apply(shared_cfg, params["shared"], flat)
+        with jax.named_scope("lm/moe/shared"):
+            y = y + mlp_apply(shared_cfg, params["shared"], flat)
     return y.reshape(shape), aux

@@ -75,7 +75,7 @@ def _tf_block_apply(
     cache_len: int,
     dense_mlp: bool,
 ):
-    h = norm_apply(cfg.norm, p["ln1"], x)
+    h = norm_apply(cfg.norm, p["ln1"], x, cfg.rms_norm_eps)
     new_cache = None
     if cfg.attention == "mla":
         if mode == "decode":
@@ -98,12 +98,13 @@ def _tf_block_apply(
                 cache_len=cache_len,
             )
     x = x + a
-    h = norm_apply(cfg.norm, p["ln2"], x)
+    h = norm_apply(cfg.norm, p["ln2"], x, cfg.rms_norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if "moe" in p:
         m, aux = moe_lib.moe_apply(cfg, p["moe"], h, impl=cfg.moe_impl)
     else:
-        m = mlp_apply(cfg, p["mlp"], h)
+        with jax.named_scope("lm/mlp"):
+            m = mlp_apply(cfg, p["mlp"], h)
     return x + m, new_cache, aux
 
 
@@ -150,6 +151,9 @@ def _hybrid_layer_init(key, cfg: ModelConfig, dtype) -> dict:
 @dataclass(frozen=True)
 class Model:
     cfg: ModelConfig
+    # rematerialise each layer in the backward pass of "train" mode; off
+    # where activations are small and recomputing them is pure cost
+    remat: bool = True
 
     # ---------------- init ------------------------------------------------
     def init(self, key) -> Dict[str, Any]:
@@ -196,10 +200,11 @@ class Model:
         return x
 
     def _head(self, params, x):
-        x = norm_apply(self.cfg.norm, params["final_norm"], x)
-        if self.cfg.tie_embeddings:
-            return x @ params["embed"].T
-        return x @ params["lm_head"]
+        with jax.named_scope("lm/head"):
+            x = norm_apply(self.cfg.norm, params["final_norm"], x, self.cfg.rms_norm_eps)
+            if self.cfg.tie_embeddings:
+                return x @ params["embed"].T
+            return x @ params["lm_head"]
 
     # ---------------- full-sequence passes ---------------------------------
     def _run_layers(self, params, x, positions, mode: str, cache, cache_len: int):
@@ -240,7 +245,7 @@ class Model:
                 h, nc, aux = _tf_block_apply(cfg, lp, h, positions, mode, None, cache_len, False)
                 return (h, auxs + aux), nc
 
-            if mode == "train":
+            if mode == "train" and self.remat:
                 body = jax.checkpoint(body, prevent_cse=False)
             (x, aux_total), new_stack = jax.lax.scan(body, (x, aux_total), params["layers"])
 

@@ -161,7 +161,8 @@ def test_prepare_and_commit_carry_their_named_scopes():
             systems._jb(nodes[0].epoch(sim.steps_per_iter, sim.minibatch)),
             systems._jb(nodes[0].val_batch(sim.val_size)),
             jnp.zeros((N + 1,), jnp.float32))
-    text = prep.lower(*args).as_text(debug_info=True)
+    # the jitted prepare takes the task's frozen state (none here) first
+    text = prep.func.lower(*prep.args, *args).as_text(debug_info=True)
     for scope in ("select", "validate", "aggregate", "train"):
         assert f"dagfl/{scope}/" in text, scope
     prepared = jax.eval_shape(prep, *args)
