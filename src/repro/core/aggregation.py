@@ -3,8 +3,8 @@
 Three interchangeable implementations of the same aggregation:
   * ``fedavg_pytree``   — tree_map weighted sum (clear, autodiff-safe),
   * ``fedavg_flat``     — the Pallas kernel over flattened params (TPU path),
-  * ``bank_average``    — one-hot matmul over the model bank (sharded path,
-                          lives in repro.core.bank).
+  * ``bank_average``    — gathers the k chosen slots of the model bank and
+                          contracts over k (lives in repro.core.bank).
 All are cross-checked in tests.
 """
 from __future__ import annotations
