@@ -23,7 +23,11 @@ discrete-event simulation:
            delivery edge fires at most ``max_ticks_per_advance`` times per
            window; an overflowing backlog is elided (the edge jumps past
            the horizon) exactly as the tick driver fast-forwards, keeping
-           the degenerate limit bitwise for any window size.
+           the degenerate limit bitwise for any window size. The loop is
+           built once (``_build_advance``) from per-layer steps — delivery
+           (with the fault step), bank service, serve, obs — and a layer
+           that is off is never traced, so the all-off program is the
+           delivery round alone.
 
 Event kinds (lexicographic tie order = intra-instant processing order,
 mirroring the tick driver: rows merge, then payloads settle, then
@@ -87,6 +91,7 @@ from repro.core.dag import DagState
 from repro.kernels import chunk_transfer as chunk_kernel
 from repro.kernels.event_pop import event_pop
 from repro.net import bank as bank_lib
+from repro.net import faults as faults_lib
 from repro.net import gossip as gossip_lib
 from repro.net import mesh as mesh_lib
 from repro.net import replica as replica_lib
@@ -202,9 +207,9 @@ def _partition_mask(t, part_mask, part_t0, part_t1):
 
 def _deliver_round(dags, qt, fires, key, t, qv, qkind, qsrc, qdst, islot,
                    horizon, fire_cap, part_mask, part_t0, part_t1, drop,
-                   nbr_idx, nbr_valid, impl):
+                   nbr_idx, nbr_valid, impl, faults=None):
     """One fused anti-entropy round over every delivery firing at instant
-    ``t`` — THE shared block all three event drivers run, so the key-split
+    ``t`` — THE shared block both event drivers run, so the key-split
     order (one per batch), the partition-window rule, and the reschedule
     arithmetic that the degenerate-limit bitwise equivalence depends on
     live in one place.
@@ -216,6 +221,12 @@ def _deliver_round(dags, qt, fires, key, t, qv, qkind, qsrc, qdst, islot,
     (never replays) a backlog that outruns the cap, so the degenerate
     uniform-delay limit stays bitwise the tick path for any window size.
 
+    ``faults`` (a ``repro.net.faults.FaultConfig``) is the fault step: the
+    edge attacks are composed onto the surviving ``live`` edges, drawn
+    from a ``fold_in`` branch of the round's sub-key (faults act at the
+    layer of drop loss: a suppressed delivery still consumed its slot),
+    and SYBIL inflation rewrites the post-round replicas.
+
     Returns ``(dags, qt, fires, key, deliver, live, pm)`` — the edge masks
     so bank callers can service the same exchanges.
     """
@@ -226,7 +237,15 @@ def _deliver_round(dags, qt, fires, key, t, qv, qkind, qsrc, qdst, islot,
     key, sub = jax.random.split(key)
     u = jax.random.uniform(sub, (n, n))
     live = deliver & pm & (u >= drop)
+    if faults is not None:
+        masks = faults_lib._role_masks(faults)
+        live = faults_lib.fault_edges(
+            faults, masks, t,
+            jax.random.fold_in(sub, faults_lib._SALT_EDGES), live,
+        )
     dags = gossip_lib._apply_round(dags, live, nbr_idx, nbr_valid, impl)
+    if faults is not None:
+        dags = faults_lib.sybil_inflate(dags, masks)
     fires = fires + batch.astype(jnp.int32)
     elide = fires >= fire_cap
     skip = (jnp.floor((horizon - qt) / islot) + 1.0) * islot
@@ -239,164 +258,119 @@ def _deliver_round(dags, qt, fires, key, t, qv, qkind, qsrc, qdst, islot,
 # ---------------------------------------------------------------------------
 
 
-@functools.lru_cache(maxsize=None)
-def _advance_events_jit(impl: str, obs=None, faults=None, serve=None):
-    """Event-driven ``advance``: one ``lax.while_loop`` over delivery batches.
+class AdvanceCarry(NamedTuple):
+    """The event advance's loop state, one field per layer.
 
-    Each iteration pops the queue head (``repro.kernels.event_pop``),
-    gathers every delivery firing at that instant, and runs the shared
-    ``_deliver_round`` block — one PRNG split per batch, exactly as the
-    tick scan splits once per tick, and per-edge fire caps that elide an
-    overflowing backlog exactly as the tick driver fast-forwards — so the
-    degenerate uniform-delay limit is bitwise the tick path, key included,
-    for any advance window.
-
-    ``obs`` (an ``repro.obs.ObsConfig``) threads the telemetry collectors
-    through the loop carry, sampled once per event batch at the batch
-    instant — a pure read, so the dags/key trajectory is bitwise the
-    ``obs=None`` program, whose body below is the untouched code.
-    ``faults`` (a ``repro.net.faults.FaultConfig``) swaps in the
-    fault-injected body — ``faults=None`` keeps the untouched program
-    below. ``serve`` (pre-mapped through ``repro.net.serve.serve_key``)
-    swaps in the inference-serving body with KIND_INFER slots live;
-    ``serve=None`` keeps the literal serve-free program below.
+    An absent layer's fields are None, an empty pytree: they add no loop
+    state, no argument and no result. The field order is the order of the
+    loop state and of the advance's result tuple (``fires`` is loop-only),
+    so it is part of the compiled program and of its compile-cache key.
     """
-    if serve is not None:
-        from repro.net import serve as serve_lib   # deferred: serve imports this module
-        return serve_lib._advance_events_serve_jit(impl, serve, obs, faults)
-    if faults is not None:
-        from repro.net import faults as faults_lib   # deferred: faults imports this module
-        return faults_lib._advance_events_faults_jit(impl, faults, obs)
 
-    if obs is None:
-        def advance(dags, qtime, qvalid, qkind, qsrc, qdst, qseq, islot, key,
-                    horizon, limit, fire_cap, part_mask, part_t0, part_t1,
-                    drop, nbr_idx, nbr_valid):
+    dags: DagState
+    bstate: Optional[bank_lib.BankState] = None   # bank
+    last_srv: Optional[jnp.ndarray] = None        # bank: (N, N) f32 last service
+    fstate: Optional[faults_lib.FaultState] = None  # faults, with the bank
+    qt: Optional[jnp.ndarray] = None              # (Q,) f32 slot times
+    qv: Optional[jnp.ndarray] = None              # (Q,) bool slot validity
+    fires: Optional[jnp.ndarray] = None           # (Q,) i32 fires this window
+    key: Optional[jnp.ndarray] = None             # the main PRNG key
+    done: Optional[jnp.ndarray] = None            # () i32 event batches run
+    sstate: Optional[object] = None               # serve: ServeState
+    metrics: Optional[object] = None              # obs: MetricsState
+    ring: Optional[object] = None                 # obs: TraceRing
 
-            def cond(carry):
-                _dags, qt, qv, _fires, _key, done = carry
-                return _queue_head_due(qt, qv, horizon) & (done < limit)
 
-            def body(carry):
-                dags, qt, qv, fires, key, done = carry
-                idx, _found = event_pop(qt, qkind, qseq, qv)
-                t = qt[idx]
-                dags, qt, fires, key, _dlv, _live, _pm = _deliver_round(
-                    dags, qt, fires, key, t, qv, qkind, qsrc, qdst, islot,
-                    horizon, fire_cap, part_mask, part_t0, part_t1, drop,
-                    nbr_idx, nbr_valid, impl,
-                )
-                return dags, qt, qv, fires, key, done + 1
-
-            dags, qt, qv, _fires, key, done = jax.lax.while_loop(
-                cond, body,
-                (dags, qtime, qvalid, jnp.zeros_like(qseq), key, jnp.int32(0)),
-            )
-            return dags, qt, qv, key, done
-
-        return jax.jit(advance)
-
-    from repro import obs as obs_lib   # deferred: repro.obs imports repro.net
-
-    def advance(dags, qtime, qvalid, qkind, qsrc, qdst, qseq, islot, key,
-                horizon, limit, fire_cap, part_mask, part_t0, part_t1, drop,
-                nbr_idx, nbr_valid, metrics, ring):
-
-        def cond(carry):
-            _dags, qt, qv = carry[0], carry[1], carry[2]
-            done = carry[7]
-            return _queue_head_due(qt, qv, horizon) & (done < limit)
-
-        def body(carry):
-            dags, qt, qv, fires, key, metrics, ring, done = carry
-            idx, _found = event_pop(qt, qkind, qseq, qv)
-            t = qt[idx]
-            old = dags
-            dags, qt, fires, key, _dlv, live, _pm = _deliver_round(
-                dags, qt, fires, key, t, qv, qkind, qsrc, qdst, islot,
-                horizon, fire_cap, part_mask, part_t0, part_t1, drop,
-                nbr_idx, nbr_valid, impl,
-            )
-            metrics, ring = obs_lib.observe_round(
-                obs, metrics, ring, t, old, dags, live_edges=live
-            )
-            return dags, qt, qv, fires, key, metrics, ring, done + 1
-
-        dags, qt, qv, _fires, key, metrics, ring, done = jax.lax.while_loop(
-            cond, body,
-            (dags, qtime, qvalid, jnp.zeros_like(qseq), key, metrics, ring,
-             jnp.int32(0)),
-        )
-        return dags, qt, qv, key, done, metrics, ring
-
-    return jax.jit(advance)
+def name_result(template: AdvanceCarry, out) -> AdvanceCarry:
+    """Name the advance's result tuple ``out`` by the fields that are not
+    None in ``template`` (the state the caller passed in)."""
+    it = iter(out)
+    return template._make(
+        None if v is None else next(it) for v in template._replace(fires=None)
+    )
 
 
 @functools.lru_cache(maxsize=None)
-def _advance_events_bank_jit(impl: str, bank_impl, obs=None, faults=None,
-                             codec=None, serve=None):
-    """Event-driven ``advance`` with the model bank gossiped.
+def _build_advance(impl: str, bank_impl, obs, faults, codec, serve):
+    """The event-driven ``advance``: one ``lax.while_loop`` over event
+    batches, built from per-layer steps.
 
-    The row half of a batch is the shared ``_deliver_round`` (fire caps and
-    all); the bank half services every edge whose delivery or drain fired,
-    with a budget
-    accrued CONTINUOUSLY since the edge's last service
-    (``(t - last_serviced) * B/8`` — the tick model's per-fire quantum is
-    the uniform-interval special case, so the unlimited-capacity degenerate
-    limit stays bitwise the tick path). A serviced link with work left over
-    arms its drain slot at the instant its next whole chunk completes; a
-    link partitioned away retries one chunk-time later without resetting
-    the rolled-over credit. ``obs`` threads the telemetry carry exactly as
-    in ``_advance_events_jit`` (``obs=None`` keeps the untouched program);
-    bank batches additionally sample chunk lag / byte totals and record a
-    DRAIN trace span per link that moved payload. ``faults`` swaps in the
-    fault-injected body (``faults=None`` keeps the untouched program
-    below). ``codec`` (pre-mapped through ``delta_codec.codec_key``)
-    scales ``chunk_bytes`` to the encoded wire size — pricing, the byte
-    meter, AND the drain-instant arithmetic all see the compressed
-    granule, so compressed chunks complete earlier in continuous time;
-    ``codec=None`` keeps the literal raw-chunk program. ``serve``
-    (pre-mapped through ``repro.net.serve.serve_key``) swaps in the
-    inference-serving body with KIND_INFER slots live — requests served
-    from the availability-GATED view; ``serve=None`` keeps the literal
-    serve-free program below.
+    Each iteration pops the queue head (``repro.kernels.event_pop``) and
+    runs the steps of the layers that are present; a layer whose config
+    (or, for the bank, whose argument) is None is never traced, so the
+    all-off program is the delivery round alone:
+
+      delivery  ``_deliver_round`` — one PRNG split per batch, exactly as
+                the tick scan splits once per tick, and per-edge fire caps
+                that elide an overflowing backlog exactly as the tick
+                driver fast-forwards — so the degenerate uniform-delay
+                limit is bitwise the tick path, key included. ``faults``
+                is its fault step.
+      bank      every edge whose delivery or drain fired is serviced with
+                a budget accrued CONTINUOUSLY since its last service
+                (``(t - last_serviced) * B/8``; the tick model's per-fire
+                quantum is the uniform-interval special case). A serviced
+                link with work left arms its drain slot at the instant its
+                next whole chunk completes; a link partitioned away retries
+                one chunk-time later without resetting its credit. With
+                ``faults`` the fault-aware chunk service (spoofing,
+                verification, back-off, quarantine) replaces ``chunk_step``.
+                ``codec`` scales ``chunk_bytes`` to the encoded wire size.
+      serve     an INFER head (``serve`` effective, pre-mapped through
+                ``serve.serve_key``) runs ``serve.infer_step`` instead of
+                the transport, against replica staleness (against the
+                availability-GATED view with the bank) and never touches
+                the main key.
+      obs       ``obs.observe_round`` once per batch at the batch instant,
+                a pure read of what the other layers did.
+
+    The program's arguments are the stacked replicas, ``bank`` =
+    ``(BankState, last_srv, digest)``, the queue, the key, the horizon and
+    limits, the wire (partition, drop, neighbour table), ``links`` =
+    ``(bw_bytes, chunk_bytes)``, the ``FaultState``, ``served`` =
+    ``(ServeState, serve_base, infer_base)`` and ``telemetry`` =
+    ``(MetricsState, TraceRing)``; an absent layer passes None, which adds
+    no argument to the program. The result is the tuple of the
+    ``AdvanceCarry`` fields that are present, dags first (``name_result``
+    names it).
     """
     if serve is not None:
+        # deferred: serve imports this module for EventQueue and KIND_INFER
         from repro.net import serve as serve_lib
-        return serve_lib._advance_events_bank_serve_jit(
-            impl, bank_impl, serve, obs, faults, codec
-        )
-    if faults is not None:
-        from repro.net import faults as faults_lib
-        return faults_lib._advance_events_bank_faults_jit(
-            impl, bank_impl, faults, obs, codec
-        )
-
     if obs is not None:
-        from repro import obs as obs_lib
+        from repro import obs as obs_lib   # deferred: repro.obs imports repro.net
+    masks = faults_lib._role_masks(faults) if faults is not None else None
 
-    def advance(dags, have, credit, sent, last_srv, digest, qtime, qvalid,
-                qkind, qsrc, qdst, qseq, islot, key, horizon, limit,
-                fire_cap, part_mask, part_t0, part_t1, drop, nbr_idx,
-                nbr_valid, bw_bytes, chunk_bytes, *obs_carry):
-        if codec is not None:
-            chunk_bytes = chunk_bytes * codec.wire_ratio()
+    def advance(dags, bank, qtime, qvalid, qkind, qsrc, qdst, qseq, islot,
+                key, horizon, limit, fire_cap, part_mask, part_t0, part_t1,
+                drop, nbr_idx, nbr_valid, links, fstate, served, telemetry):
+        bstate = last_srv = sstate = metrics = ring = None
+        if bank is not None:
+            bstate, last_srv, digest = bank
+            bw_bytes, chunk_bytes = links
+            if codec is not None:
+                chunk_bytes = chunk_bytes * codec.wire_ratio()
+        if served is not None:
+            sstate, serve_base, infer_base = served
+        if telemetry is not None:
+            metrics, ring = telemetry
         n = dags.publisher.shape[0]
 
-        def cond(carry):
-            qt, qv, done = carry[4], carry[5], carry[7]
-            return _queue_head_due(qt, qv, horizon) & (done < limit)
+        def deliver(op, t, qv):
+            return _deliver_round(
+                *op, t, qv, qkind, qsrc, qdst, islot, horizon, fire_cap,
+                part_mask, part_t0, part_t1, drop, nbr_idx, nbr_valid, impl,
+                faults,
+            )
 
-        def body(carry):
-            if obs is not None:
-                (dags, bstate, last_srv, key, qt, qv, fires, done,
-                 metrics, ring) = carry
-                old_dags, old_sent, old_have = dags, bstate.sent, bstate.have
-            else:
-                dags, bstate, last_srv, key, qt, qv, fires, done = carry
-            idx, _found = event_pop(qt, qkind, qseq, qv)
-            t = qt[idx]
-            batch = qv & (qt == t)
+        def gated_staleness(c):
+            sat = (None if bank is None else
+                   chunk_kernel.chunk_dedup(c.bstate.have, digest,
+                                            impl=bank_impl))
+            return serve_lib.gated_staleness(c.dags, sat)
+
+        def bank_service(c, t):
+            batch = c.qv & (c.qt == t)
             is_drn = qkind == KIND_DRAIN
             drain = _edge_mask(n, qdst, qsrc, batch & is_drn)
 
@@ -405,37 +379,43 @@ def _advance_events_bank_jit(impl: str, bank_impl, obs=None, faults=None,
             # drain moves payload bytes, not rows. Deliveries always take
             # the round branch, so the degenerate unlimited-capacity limit
             # (where drains never arm) is untouched.
-            def _with_round(op):
-                return _deliver_round(
-                    *op, t, qv, qkind, qsrc, qdst, islot, horizon, fire_cap,
-                    part_mask, part_t0, part_t1, drop, nbr_idx, nbr_valid,
-                    impl,
-                )
-
-            def _no_round(op):
-                dags, qt, fires, key = op
+            def no_round(op):
                 off = jnp.zeros((n, n), bool)
                 pm = _partition_mask(t, part_mask, part_t0, part_t1)
-                return dags, qt, fires, key, off, off, pm
+                return (*op, off, off, pm)
 
-            dags, qt, fires, key, deliver, live, pm = jax.lax.cond(
+            dags, qt, fires, key, delivered, live, pm = jax.lax.cond(
                 jnp.any(batch & (qkind == KIND_DELIVER)),
-                _with_round, _no_round, (dags, qt, fires, key),
+                lambda op: deliver(op, t, c.qv), no_round,
+                (c.dags, c.qt, c.fires, c.key),
             )
-            # bank service: surviving deliveries carry chunks in the same
-            # exchange; drains are transfer continuations (partition-gated,
-            # not loss-gated). Budget = continuous accrual since last fire.
+            # surviving deliveries carry chunks in the same exchange; drains
+            # are transfer continuations (partition-gated, not loss-gated).
+            # Budget = continuous accrual since last fire.
             svc = live | (drain & pm)
-            sched = deliver | drain
-            accr = jnp.where(svc, (t - last_srv) * bw_bytes, 0.0)
-            sat = chunk_kernel.chunk_dedup(bstate.have, digest, impl=bank_impl)
-            bstate, pending = bank_lib.chunk_step(
-                dags, bstate, digest, sat, sat, svc, accr, chunk_bytes,
-                return_pending=True,
-            )
+            sched = delivered | drain
+            accr = jnp.where(svc, (t - c.last_srv) * bw_bytes, 0.0)
+            if faults is None:
+                sat = chunk_kernel.chunk_dedup(c.bstate.have, digest,
+                                               impl=bank_impl)
+                bstate, pending = bank_lib.chunk_step(
+                    dags, c.bstate, digest, sat, sat, svc, accr, chunk_bytes,
+                    return_pending=True,
+                )
+                fstate = None
+            else:
+                # the batch counter is folded in: drain-only batches do not
+                # split the main key, so the salt alone would repeat draws
+                skey = jax.random.fold_in(
+                    jax.random.fold_in(key, faults_lib._SALT_SPOOF), c.done
+                )
+                bstate, fstate, pending = faults_lib._fault_chunk_service(
+                    dags, c.bstate, c.fstate, digest, svc, accr, chunk_bytes,
+                    skey, faults, masks, bank_impl,
+                )
             # a fired-but-suppressed exchange wastes its window (idle
             # bandwidth is never banked) — the accrual clock resets either way
-            last_srv = jnp.where(sched, t, last_srv)
+            last_srv = jnp.where(sched, t, c.last_srv)
             # drain slots: serviced edges re-arm from `pending` at the next
             # whole-chunk completion; suppressed fired drains retry later.
             # Strict progress: f32 accrual residue can leave `credit` within
@@ -444,7 +424,8 @@ def _advance_events_bank_jit(impl: str, bank_impl, obs=None, faults=None,
             # re-arm at its own time and livelock the advance against
             # max_events_per_advance, starving every event behind it. Clamp
             # each re-arm to the next representable instant (a no-op for any
-            # re-arm that already lands strictly past t).
+            # re-arm that already lands strictly past t). A quarantined link
+            # gets no stripe, so its drain disarms while deliveries go on.
             rate = jnp.maximum(bw_bytes, 1e-9)
             t_next = jnp.nextafter(t, jnp.float32(jnp.inf))
             e_next = jnp.maximum(
@@ -453,28 +434,115 @@ def _advance_events_bank_jit(impl: str, bank_impl, obs=None, faults=None,
             e_retry = jnp.maximum(t + chunk_bytes / rate, t_next)[qdst, qsrc]
             e_svc = svc[qdst, qsrc]
             e_pend = pending[qdst, qsrc]
-            qv = jnp.where(is_drn & e_svc, e_pend, qv)
+            qv = jnp.where(is_drn & e_svc, e_pend, c.qv)
             qt = jnp.where(is_drn & e_svc,
                            jnp.where(e_pend, e_next, jnp.inf), qt)
             qt = jnp.where(batch & is_drn & ~e_svc, e_retry, qt)
-            if obs is not None:
-                metrics2, ring2 = obs_lib.observe_round(
-                    obs, metrics, ring, t, old_dags, dags, live_edges=live,
-                    bytes_delta=bstate.sent - old_sent, bstate=bstate,
-                    digest=digest, bank_impl=bank_impl, old_have=old_have,
-                )
-                return (dags, bstate, last_srv, key, qt, qv, fires, done + 1,
-                        metrics2, ring2)
-            return dags, bstate, last_srv, key, qt, qv, fires, done + 1
+            return c._replace(dags=dags, bstate=bstate, last_srv=last_srv,
+                              fstate=fstate, qt=qt, qv=qv, fires=fires,
+                              key=key), live
 
-        init = (dags, bank_lib.BankState(have=have, credit=credit, sent=sent),
-                last_srv, key, qtime, qvalid, jnp.zeros_like(qseq),
-                jnp.int32(0)) + tuple(obs_carry)
+        def transport(c, t):
+            if bank is not None:
+                return bank_service(c, t)
+            dags, qt, fires, key, _dlv, live, _pm = deliver(
+                (c.dags, c.qt, c.fires, c.key), t, c.qv
+            )
+            return c._replace(dags=dags, qt=qt, fires=fires, key=key), live
+
+        def step(c, t, idx):
+            """(carry, live edges, serve admits or None) of the batch at t."""
+            if serve is None:
+                c, live = transport(c, t)
+                return c, live, None
+
+            def net(c):
+                c, live = transport(c, t)
+                return c, live, (jnp.zeros((n,), bool),
+                                 jnp.zeros((n,), jnp.int32),
+                                 jnp.full((), -1, jnp.int32))
+
+            def infer(c):
+                stale = gated_staleness(c)
+                sstate, qt, qv, admitted, batch_now = serve_lib.infer_step(
+                    serve, c.sstate, t, c.qt, c.qv, qkind, qseq, infer_base,
+                    serve_base, stale,
+                )
+                s_now = jnp.max(jnp.where(admitted, stale, -1)).astype(
+                    jnp.int32
+                )
+                return (c._replace(sstate=sstate, qt=qt, qv=qv),
+                        jnp.zeros((n, n), bool), (admitted, batch_now, s_now))
+
+            # INFER sorts after every transport kind at an equal instant, so
+            # same-instant requests are served post-merge
+            return jax.lax.cond(qkind[idx] == KIND_INFER, infer, net, c)
+
+        def observe(old, new, t, live, admits):
+            kw = {}
+            if bank is not None:
+                kw.update(bytes_delta=new.bstate.sent - old.bstate.sent,
+                          bstate=new.bstate, digest=digest,
+                          bank_impl=bank_impl, old_have=old.bstate.have)
+            if new.fstate is not None:
+                kw.update(rejects=new.fstate.rejects,
+                          rejects_delta=new.fstate.rejects - old.fstate.rejects,
+                          quarantine_after=faults.quarantine_after)
+            if serve is not None:
+                admitted, batch_now, s_now = admits
+                kw.update(serve_counts=new.sstate.served, serve_stale=s_now,
+                          infer_nodes=admitted, infer_arg=batch_now)
+                if obs.hist is not None:
+                    # the stale vector only weighs in when a batch admitted
+                    # (an INFER head, which left dags and bank untouched), so
+                    # recomputing it here reads exactly what infer_step saw
+                    kw.update(
+                        serve_stale_node=gated_staleness(new),
+                        serve_arrived=(new.sstate.arrivals
+                                       - old.sstate.arrivals),
+                        serve_enq=(new.sstate.queued - old.sstate.queued
+                                   + batch_now),
+                        serve_queued=new.sstate.queued,
+                    )
+            return obs_lib.observe_round(
+                obs, old.metrics, old.ring, t, old.dags, new.dags,
+                live_edges=live, **kw,
+            )
+
+        def cond(c):
+            return _queue_head_due(c.qt, c.qv, horizon) & (c.done < limit)
+
+        def body(c):
+            idx, _found = event_pop(c.qt, qkind, qseq, c.qv)
+            t = c.qt[idx]
+            new, live, admits = step(c._replace(metrics=None, ring=None),
+                                     t, idx)
+            if obs is not None:
+                metrics, ring = observe(c, new, t, live, admits)
+                new = new._replace(metrics=metrics, ring=ring)
+            return new._replace(done=c.done + 1)
+
+        init = AdvanceCarry(
+            dags=dags, bstate=bstate, last_srv=last_srv, fstate=fstate,
+            qt=qtime, qv=qvalid, fires=jnp.zeros_like(qseq), key=key,
+            done=jnp.int32(0), sstate=sstate, metrics=metrics, ring=ring,
+        )
         out = jax.lax.while_loop(cond, body, init)
-        dags, bstate, last_srv, key, qt, qv, _fires, done = out[:8]
-        return (dags, bstate, last_srv, key, qt, qv, done) + out[8:]
+        return tuple(v for v in out._replace(fires=None) if v is not None)
 
     return jax.jit(advance)
+
+
+def _advance_events_jit(impl: str, obs=None, faults=None, serve=None):
+    """The event ``advance`` without the bank (``_build_advance``)."""
+    return _build_advance(impl, None, obs, faults, None, serve)
+
+
+def _advance_events_bank_jit(impl: str, bank_impl, obs=None, faults=None,
+                             codec=None, serve=None):
+    """The event ``advance`` with the model bank gossiped
+    (``_build_advance``; the caller passes the bank arguments)."""
+    return _build_advance(impl, bank_impl, obs, faults, codec, serve)
 
 
 # ---------------------------------------------------------------------------

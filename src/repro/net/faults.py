@@ -59,9 +59,12 @@ whose roles are all-HONEST is bitwise the ``faults=None`` path (tested),
 and enabling e.g. a crash window does not perturb the drop-loss draws.
 
 ``faults=None`` in `GossipNetwork` keeps every existing code path
-literally untouched — the jit factories in gossip.py/events.py return
-their pre-PR bodies and dispatch here only when a ``FaultConfig`` is
-passed (the ``obs=None`` pattern).
+literally untouched (the ``obs=None`` pattern). The tick engine's jit
+factories in gossip.py dispatch to the faulted twins below only when a
+``FaultConfig`` is passed; the event engine's one loop body
+(``events._build_advance``) calls ``fault_edges`` / ``sybil_inflate``
+from its delivery round and ``_fault_chunk_service`` from its bank
+service, and traces neither without one.
 """
 from __future__ import annotations
 
@@ -76,7 +79,6 @@ import numpy as np
 from repro.core.dag import DagState
 from repro.kernels import chunk_transfer as chunk_kernel
 from repro.net import bank as bank_lib
-from repro.net import events as events_lib
 from repro.net import gossip as gossip_lib
 from repro.net import replica as replica_lib
 
@@ -680,229 +682,3 @@ def _converge_bank_faults_jit(impl: str, bank_impl, faults: FaultConfig,
                 synced(dags, bstate, digest), metrics, ring)
 
     return jax.jit(converge)
-
-
-# ---------------------------------------------------------------------------
-# Event engine: faulted variants of events.py's two jit factories
-# ---------------------------------------------------------------------------
-
-
-def _deliver_round_faults(cfg, masks, impl, dags, qt, fires, key, t, qv,
-                          qkind, qsrc, qdst, islot, horizon, fire_cap,
-                          part_mask, part_t0, part_t1, drop, nbr_idx,
-                          nbr_valid):
-    """Faulted ``events._deliver_round``: identical batch/PRNG/reschedule
-    arithmetic, with the fault mask composed onto the surviving ``live``
-    edges (faults act at the same layer as drop loss — a delivery the
-    adversary suppresses still consumed its queue slot) and SYBIL
-    inflation applied to the post-round replicas."""
-    n = dags.publisher.shape[0]
-    batch = qv & (qt == t) & (qkind == events_lib.KIND_DELIVER)
-    deliver = events_lib._edge_mask(n, qdst, qsrc, batch)
-    pm = events_lib._partition_mask(t, part_mask, part_t0, part_t1)
-    key, sub = jax.random.split(key)
-    u = jax.random.uniform(sub, (n, n))
-    live = deliver & pm & (u >= drop)
-    live = fault_edges(
-        cfg, masks, t, jax.random.fold_in(sub, _SALT_EDGES), live
-    )
-    dags = gossip_lib._apply_round(dags, live, nbr_idx, nbr_valid, impl)
-    dags = sybil_inflate(dags, masks)
-    fires = fires + batch.astype(jnp.int32)
-    elide = fires >= fire_cap
-    skip = (jnp.floor((horizon - qt) / islot) + 1.0) * islot
-    qt = jnp.where(batch, qt + jnp.where(elide, skip, islot), qt)
-    return dags, qt, fires, key, deliver, live, pm
-
-
-@functools.lru_cache(maxsize=None)
-def _advance_events_faults_jit(impl: str, faults: FaultConfig, obs=None):
-    """Faulted ``events._advance_events_jit`` (bankless)."""
-    from repro.kernels.event_pop import event_pop
-
-    masks = _role_masks(faults)
-
-    if obs is None:
-        def advance(dags, qtime, qvalid, qkind, qsrc, qdst, qseq, islot, key,
-                    horizon, limit, fire_cap, part_mask, part_t0, part_t1,
-                    drop, nbr_idx, nbr_valid):
-
-            def cond(carry):
-                _dags, qt, qv, _fires, _key, done = carry
-                return events_lib._queue_head_due(qt, qv, horizon) & (
-                    done < limit
-                )
-
-            def body(carry):
-                dags, qt, qv, fires, key, done = carry
-                idx, _found = event_pop(qt, qkind, qseq, qv)
-                t = qt[idx]
-                dags, qt, fires, key, _dlv, _live, _pm = (
-                    _deliver_round_faults(
-                        faults, masks, impl, dags, qt, fires, key, t, qv,
-                        qkind, qsrc, qdst, islot, horizon, fire_cap,
-                        part_mask, part_t0, part_t1, drop, nbr_idx,
-                        nbr_valid,
-                    )
-                )
-                return dags, qt, qv, fires, key, done + 1
-
-            dags, qt, qv, _fires, key, done = jax.lax.while_loop(
-                cond, body,
-                (dags, qtime, qvalid, jnp.zeros_like(qseq), key,
-                 jnp.int32(0)),
-            )
-            return dags, qt, qv, key, done
-
-        return jax.jit(advance)
-
-    from repro import obs as obs_lib
-
-    def advance(dags, qtime, qvalid, qkind, qsrc, qdst, qseq, islot, key,
-                horizon, limit, fire_cap, part_mask, part_t0, part_t1, drop,
-                nbr_idx, nbr_valid, metrics, ring):
-
-        def cond(carry):
-            _dags, qt, qv = carry[0], carry[1], carry[2]
-            done = carry[7]
-            return events_lib._queue_head_due(qt, qv, horizon) & (done < limit)
-
-        def body(carry):
-            dags, qt, qv, fires, key, metrics, ring, done = carry
-            idx, _found = event_pop(qt, qkind, qseq, qv)
-            t = qt[idx]
-            old = dags
-            dags, qt, fires, key, _dlv, live, _pm = _deliver_round_faults(
-                faults, masks, impl, dags, qt, fires, key, t, qv, qkind,
-                qsrc, qdst, islot, horizon, fire_cap, part_mask, part_t0,
-                part_t1, drop, nbr_idx, nbr_valid,
-            )
-            metrics, ring = obs_lib.observe_round(
-                obs, metrics, ring, t, old, dags, live_edges=live
-            )
-            return dags, qt, qv, fires, key, metrics, ring, done + 1
-
-        dags, qt, qv, _fires, key, metrics, ring, done = jax.lax.while_loop(
-            cond, body,
-            (dags, qtime, qvalid, jnp.zeros_like(qseq), key, metrics, ring,
-             jnp.int32(0)),
-        )
-        return dags, qt, qv, key, done, metrics, ring
-
-    return jax.jit(advance)
-
-
-@functools.lru_cache(maxsize=None)
-def _advance_events_bank_faults_jit(impl: str, bank_impl,
-                                    faults: FaultConfig, obs=None,
-                                    codec=None):
-    """Faulted ``events._advance_events_bank_jit``.
-
-    Batch structure, continuous budget accrual, and drain re-arm are the
-    originals; the chunk service is the fault-aware one. A quarantined
-    link gets no stripe assignment, so its drain slot disarms (pending is
-    False for it) while deliveries keep firing — the overlay routes
-    around it at zero queue cost. The per-batch spoof key folds the batch
-    counter in (drain-only batches do not split the main key, so the salt
-    alone would repeat draws across consecutive drains)."""
-    from repro.kernels.event_pop import event_pop
-
-    masks = _role_masks(faults)
-
-    if obs is not None:
-        from repro import obs as obs_lib
-
-    def advance(dags, have, credit, sent, fstate, last_srv, digest, qtime,
-                qvalid, qkind, qsrc, qdst, qseq, islot, key, horizon, limit,
-                fire_cap, part_mask, part_t0, part_t1, drop, nbr_idx,
-                nbr_valid, bw_bytes, chunk_bytes, *obs_carry):
-        if codec is not None:
-            chunk_bytes = chunk_bytes * codec.wire_ratio()
-        n = dags.publisher.shape[0]
-
-        def cond(carry):
-            qt, qv, done = carry[5], carry[6], carry[8]
-            return events_lib._queue_head_due(qt, qv, horizon) & (done < limit)
-
-        def body(carry):
-            if obs is not None:
-                (dags, bstate, fstate, last_srv, key, qt, qv, fires, done,
-                 metrics, ring) = carry
-                old_dags, old_sent, old_rej = dags, bstate.sent, fstate.rejects
-                old_have = bstate.have
-            else:
-                (dags, bstate, fstate, last_srv, key, qt, qv, fires,
-                 done) = carry
-            idx, _found = event_pop(qt, qkind, qseq, qv)
-            t = qt[idx]
-            batch = qv & (qt == t)
-            is_drn = qkind == events_lib.KIND_DRAIN
-            drain = events_lib._edge_mask(n, qdst, qsrc, batch & is_drn)
-
-            def _with_round(op):
-                return _deliver_round_faults(
-                    faults, masks, impl, *op, t, qv, qkind, qsrc, qdst,
-                    islot, horizon, fire_cap, part_mask, part_t0, part_t1,
-                    drop, nbr_idx, nbr_valid,
-                )
-
-            def _no_round(op):
-                dags, qt, fires, key = op
-                off = jnp.zeros((n, n), bool)
-                pm = events_lib._partition_mask(t, part_mask, part_t0,
-                                                part_t1)
-                return dags, qt, fires, key, off, off, pm
-
-            dags, qt, fires, key, deliver, live, pm = jax.lax.cond(
-                jnp.any(batch & (qkind == events_lib.KIND_DELIVER)),
-                _with_round, _no_round, (dags, qt, fires, key),
-            )
-            svc = live | (drain & pm)
-            sched = deliver | drain
-            accr = jnp.where(svc, (t - last_srv) * bw_bytes, 0.0)
-            skey = jax.random.fold_in(
-                jax.random.fold_in(key, _SALT_SPOOF), done
-            )
-            bstate, fstate, pending = _fault_chunk_service(
-                dags, bstate, fstate, digest, svc, accr, chunk_bytes, skey,
-                faults, masks, bank_impl,
-            )
-            last_srv = jnp.where(sched, t, last_srv)
-            # strict-progress clamp: see the serve-free drain re-arm in
-            # events.py — an f32 credit residue can round the completion
-            # instant back to t and livelock the advance
-            rate = jnp.maximum(bw_bytes, 1e-9)
-            t_next = jnp.nextafter(t, jnp.float32(jnp.inf))
-            e_next = jnp.maximum(
-                t + (chunk_bytes - bstate.credit) / rate, t_next
-            )[qdst, qsrc]
-            e_retry = jnp.maximum(t + chunk_bytes / rate, t_next)[qdst, qsrc]
-            e_svc = svc[qdst, qsrc]
-            e_pend = pending[qdst, qsrc]
-            qv = jnp.where(is_drn & e_svc, e_pend, qv)
-            qt = jnp.where(is_drn & e_svc,
-                           jnp.where(e_pend, e_next, jnp.inf), qt)
-            qt = jnp.where(batch & is_drn & ~e_svc, e_retry, qt)
-            if obs is not None:
-                metrics2, ring2 = obs_lib.observe_round(
-                    obs, metrics, ring, t, old_dags, dags, live_edges=live,
-                    bytes_delta=bstate.sent - old_sent, bstate=bstate,
-                    digest=digest, bank_impl=bank_impl, old_have=old_have,
-                    rejects=fstate.rejects,
-                    rejects_delta=fstate.rejects - old_rej,
-                    quarantine_after=faults.quarantine_after,
-                )
-                return (dags, bstate, fstate, last_srv, key, qt, qv, fires,
-                        done + 1, metrics2, ring2)
-            return (dags, bstate, fstate, last_srv, key, qt, qv, fires,
-                    done + 1)
-
-        init = (dags,
-                bank_lib.BankState(have=have, credit=credit, sent=sent),
-                fstate, last_srv, key, qtime, qvalid,
-                jnp.zeros_like(qseq), jnp.int32(0)) + tuple(obs_carry)
-        out = jax.lax.while_loop(cond, body, init)
-        dags, bstate, fstate, last_srv, key, qt, qv, _fires, done = out[:9]
-        return (dags, bstate, fstate, last_srv, key, qt, qv, done) + out[9:]
-
-    return jax.jit(advance)

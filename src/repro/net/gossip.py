@@ -1439,150 +1439,57 @@ class GossipNetwork:
     def _advance_events(self, t: float) -> None:
         """Run every continuous-time event at or before ``t`` as ONE jitted
         while-loop dispatch (``repro.net.events``). Delivery slots recycle
-        in place, so the queue state simply persists across calls."""
-        if self._serve is not None:
-            self._advance_events_serve(t)
-            return
+        in place, so the queue state simply persists across calls. The
+        layers that are present (bank, faults, serve, obs) pass their
+        state; the result is written back by field name."""
         from repro.net import events as events_lib
 
-        limit = jnp.int32(self.cfg.max_events_per_advance)
-        fire_cap = jnp.int32(self.cfg.max_ticks_per_advance)
-        fl = self.faults_cfg
-        if self.bank_cfg is not None:
+        bank, q = self.bank_cfg is not None, self._equeue
+        serve, obs = self._serve is not None, self.obs_cfg is not None
+        if bank:
             fn = events_lib._advance_events_bank_jit(
-                self.cfg.impl, self.bank_cfg.impl, self.obs_cfg, fl,
-                self._codec,
+                self.cfg.impl, self.bank_cfg.impl, self.obs_cfg,
+                self.faults_cfg, self._codec, self._serve,
             )
-            args = (
-                self.replicas.dags, self.replicas.bank_state.have,
-                self.replicas.bank_state.credit,
-                self.replicas.bank_state.sent, self._last_srv,
-                self._digest, self._equeue.time, self._equeue.valid,
-                self._equeue.kind, self._equeue.src, self._equeue.dst,
-                self._equeue.seq, self._eislot, self._key,
-                jnp.float32(t), limit, fire_cap, self._part_mask,
-                self._part_t0, self._part_t1, self._drop, self._nbr_idx,
-                self._nbr_valid, self._bw_bytes, self._chunk_bytes,
-            )
-            if fl is not None:
-                # the faulted body takes the FaultState after sent and
-                # returns it too
-                args = args[:4] + (self._fstate,) + args[4:]
-                if self.obs_cfg is None:
-                    (dags, bstate, self._fstate, self._last_srv, self._key,
-                     qt, qv, done) = self._dispatch(
-                        "advance_events_bank", fn, *args
-                    )
-                else:
-                    (dags, bstate, self._fstate, self._last_srv, self._key,
-                     qt, qv, done, self._metrics, self._ring) = (
-                        self._dispatch(
-                            "advance_events_bank", fn, *args,
-                            self._metrics, self._ring,
-                        )
-                    )
-            elif self.obs_cfg is None:
-                dags, bstate, self._last_srv, self._key, qt, qv, done = (
-                    self._dispatch("advance_events_bank", fn, *args)
-                )
-            else:
-                (dags, bstate, self._last_srv, self._key, qt, qv, done,
-                 self._metrics, self._ring) = self._dispatch(
-                    "advance_events_bank", fn, *args,
-                    self._metrics, self._ring,
-                )
-            self.replicas = self.replicas._replace(dags=dags, bank_state=bstate)
-        else:
-            fn = events_lib._advance_events_jit(self.cfg.impl, self.obs_cfg,
-                                                fl)
-            args = (
-                self.replicas.dags, self._equeue.time, self._equeue.valid,
-                self._equeue.kind, self._equeue.src, self._equeue.dst,
-                self._equeue.seq, self._eislot, self._key, jnp.float32(t),
-                limit, fire_cap, self._part_mask, self._part_t0,
-                self._part_t1, self._drop, self._nbr_idx, self._nbr_valid,
-            )
-            if self.obs_cfg is None:
-                dags, qt, qv, self._key, done = self._dispatch(
-                    "advance_events", fn, *args
-                )
-            else:
-                dags, qt, qv, self._key, done, self._metrics, self._ring = (
-                    self._dispatch(
-                        "advance_events", fn, *args,
-                        self._metrics, self._ring,
-                    )
-                )
-            self.replicas = self.replicas._replace(dags=dags)
-        self._equeue = self._equeue._replace(time=qt, valid=qv)
-        done = int(self._fetch("advance", done))
-        self.tick += done
-        self.rounds_run += done
-        self.events_processed += done
-
-    def _advance_events_serve(self, t: float) -> None:
-        """The event advance with the inference-serving slots live
-        (``repro.net.serve``): same loop, same transport program, plus
-        KIND_INFER batches that never split the main key. The dict result
-        avoids a combinatorial tuple-unpack over bank x faults x obs."""
-        from repro.net import events as events_lib
-
-        limit = jnp.int32(self.cfg.max_events_per_advance)
-        fire_cap = jnp.int32(self.cfg.max_ticks_per_advance)
-        fl = self.faults_cfg
-        obs_carry = (
-            (self._metrics, self._ring) if self.obs_cfg is not None else ()
-        )
-        if self.bank_cfg is not None:
-            fn = events_lib._advance_events_bank_jit(
-                self.cfg.impl, self.bank_cfg.impl, self.obs_cfg, fl,
-                self._codec, self._serve,
-            )
-            args = (
-                self.replicas.dags, self.replicas.bank_state.have,
-                self.replicas.bank_state.credit,
-                self.replicas.bank_state.sent, self._last_srv,
-                self._digest, self._equeue.time, self._equeue.valid,
-                self._equeue.kind, self._equeue.src, self._equeue.dst,
-                self._equeue.seq, self._eislot, self._key,
-                jnp.float32(t), limit, fire_cap, self._part_mask,
-                self._part_t0, self._part_t1, self._drop, self._nbr_idx,
-                self._nbr_valid, self._bw_bytes, self._chunk_bytes,
-                self._sstate, self._serve_base, self._infer_base,
-            )
-            if fl is not None:
-                args = args[:4] + (self._fstate,) + args[4:]
-            out = self._dispatch(
-                "advance_events_bank_serve", fn, *args, *obs_carry
-            )
-            self.replicas = self.replicas._replace(
-                dags=out["dags"], bank_state=out["bstate"]
-            )
-            if fl is not None:
-                self._fstate = out["fstate"]
-            self._last_srv = out["last_srv"]
         else:
             fn = events_lib._advance_events_jit(
-                self.cfg.impl, self.obs_cfg, fl, self._serve
+                self.cfg.impl, self.obs_cfg, self.faults_cfg, self._serve
             )
-            args = (
-                self.replicas.dags, self._equeue.time, self._equeue.valid,
-                self._equeue.kind, self._equeue.src, self._equeue.dst,
-                self._equeue.seq, self._eislot, self._key, jnp.float32(t),
-                limit, fire_cap, self._part_mask, self._part_t0,
-                self._part_t1, self._drop, self._nbr_idx, self._nbr_valid,
-                self._sstate, self._serve_base, self._infer_base,
-            )
-            out = self._dispatch(
-                "advance_events_serve", fn, *args, *obs_carry
-            )
-            self.replicas = self.replicas._replace(dags=out["dags"])
-        self._key = out["key"]
-        self._sstate = out["sstate"]
-        if self.obs_cfg is not None:
-            self._metrics, self._ring = out["metrics"], out["ring"]
-        self._equeue = self._equeue._replace(time=out["qt"], valid=out["qv"])
-        done = int(self._fetch("advance", out["done"]))
+        state = events_lib.AdvanceCarry(
+            dags=self.replicas.dags,
+            bstate=self.replicas.bank_state if bank else None,
+            last_srv=self._last_srv if bank else None, fstate=self._fstate,
+            qt=q.time, qv=q.valid, key=self._key, done=0,
+            sstate=self._sstate if serve else None,
+            metrics=self._metrics if obs else None,
+            ring=self._ring if obs else None,
+        )
+        out = events_lib.name_result(state, self._dispatch(
+            "advance_events", fn, state.dags,
+            (state.bstate, state.last_srv, self._digest) if bank else None,
+            q.time, q.valid, q.kind, q.src, q.dst, q.seq, self._eislot,
+            self._key, jnp.float32(t),
+            jnp.int32(self.cfg.max_events_per_advance),
+            jnp.int32(self.cfg.max_ticks_per_advance), self._part_mask,
+            self._part_t0, self._part_t1, self._drop, self._nbr_idx,
+            self._nbr_valid,
+            (self._bw_bytes, self._chunk_bytes) if bank else None,
+            self._fstate,
+            (self._sstate, self._serve_base, self._infer_base)
+            if serve else None,
+            (self._metrics, self._ring) if obs else None,
+        ))
+        self.replicas = self.replicas._replace(dags=out.dags)
+        if bank:
+            self.replicas = self.replicas._replace(bank_state=out.bstate)
+            self._last_srv = out.last_srv
+        self._fstate, self._key = out.fstate, out.key
+        if serve:
+            self._sstate = out.sstate
+        if obs:
+            self._metrics, self._ring = out.metrics, out.ring
+        self._equeue = q._replace(time=out.qt, valid=out.qv)
+        done = int(self._fetch("advance", out.done))
         self.tick += done
         self.rounds_run += done
         self.events_processed += done

@@ -36,14 +36,17 @@ delivery edges) and N batch-completion slots (armed at admit, disarmed
 at completion). INFER sorts after every transport kind at an equal
 instant, so a same-instant delivery batch pops first and the request is
 served from the *post-merge* view. INFER batches never split the main
-PRNG key — the serve layer draws only from its own fold_in branch.
+PRNG key — the serve layer draws only from its own fold_in branch. The
+INFER head is a step of the event engine's one loop body
+(``events._build_advance``), which runs ``infer_step`` below in place of
+the transport step.
 
 Degenerate limit (the obs=None / faults=None / codec=None pattern):
 ``serve_key`` maps ``None`` and any ``rate <= 0`` config to ``None``,
-under which the engines compile their LITERAL pre-serve programs — the
-PR-8 trajectory, replicas / bank state / PRNG key alike, is preserved
-bitwise by construction (pinned in ``tests/test_serve.py`` and the
-``--smoke`` tripwire).
+under which the serve step is never traced and the engine compiles its
+LITERAL pre-serve program — the PR-8 trajectory, replicas / bank state /
+PRNG key alike, is preserved bitwise by construction (pinned in
+``tests/test_serve.py`` and the ``--smoke`` tripwire).
 
 Entry points: ``GossipNetwork(serve_cfg=ServeConfig(...))`` →
 ``serve_report()``; ``run_dagfl_gossip(serve=...)`` →
@@ -53,15 +56,12 @@ link classes; ``docs/SERVING.md`` documents the semantics.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import chunk_transfer as chunk_kernel
-from repro.kernels.event_pop import event_pop
 from repro.net import bank as bank_lib
 from repro.net import events as events_lib
 from repro.net import replica as replica_lib
@@ -262,7 +262,7 @@ def extend_queue(queue: events_lib.EventQueue, islot, num_nodes: int,
 
 
 # ---------------------------------------------------------------------------
-# The INFER batch step (runs inside the jitted event loops)
+# The INFER batch step (runs inside the jitted event loop)
 # ---------------------------------------------------------------------------
 
 
@@ -369,351 +369,6 @@ def infer_step(cfg: ServeConfig, sstate: ServeState, t, qt, qv, qkind, qseq,
         cursor=cursor, sdropped=sdropped,
     )
     return out, qt, qv, can, batch_now
-
-
-# ---------------------------------------------------------------------------
-# Event-engine advance factories with the serve slots live
-# ---------------------------------------------------------------------------
-
-
-def _deliver_fn(impl: str, faults):
-    """The shared delivery-batch block, faulted or not, with a uniform
-    positional signature (dags, qt, fires, key, t, qv, qkind, qsrc, qdst,
-    islot, horizon, fire_cap, part_mask, part_t0, part_t1, drop, nbr_idx,
-    nbr_valid) -> (dags, qt, fires, key, deliver, live, pm)."""
-    if faults is None:
-        return lambda *a: events_lib._deliver_round(*a, impl)
-    from repro.net import faults as faults_lib
-    masks = faults_lib._role_masks(faults)
-    return lambda *a: faults_lib._deliver_round_faults(
-        faults, masks, impl, *a
-    )
-
-
-@functools.lru_cache(maxsize=None)
-def _advance_events_serve_jit(impl: str, serve: ServeConfig, obs=None,
-                              faults=None):
-    """Bankless event advance with inference load (``serve`` effective).
-
-    The loop body branches on the POPPED HEAD's kind: transport kinds run
-    the shared delivery block exactly as the serve-free program (one main-
-    key split per delivery batch); an INFER head runs ``infer_step``
-    against plain replica staleness (no bank to gate on) and never
-    touches the main key. INFER sorts after DELIVER at an equal instant,
-    so same-instant requests are served post-merge. Returns a dict
-    (dags / qt / qv / key / done / sstate [/ metrics / ring]).
-    """
-    deliver = _deliver_fn(impl, faults)
-    if obs is not None:
-        from repro import obs as obs_lib
-
-    def advance(dags, qtime, qvalid, qkind, qsrc, qdst, qseq, islot, key,
-                horizon, limit, fire_cap, part_mask, part_t0, part_t1,
-                drop, nbr_idx, nbr_valid, sstate, serve_base, infer_base,
-                *obs_carry):
-        n = dags.publisher.shape[0]
-
-        def cond(carry):
-            qt, qv, done = carry[1], carry[2], carry[5]
-            return events_lib._queue_head_due(qt, qv, horizon) & (done < limit)
-
-        def body(carry):
-            if obs is not None:
-                dags, qt, qv, fires, key, done, sstate, metrics, ring = carry
-                old_sstate = sstate
-            else:
-                dags, qt, qv, fires, key, done, sstate = carry
-            idx, _found = event_pop(qt, qkind, qseq, qv)
-            t = qt[idx]
-            knd = qkind[idx]
-            old = dags
-
-            def do_net(op):
-                dags, qt, qv, fires, key, sstate = op
-                dags, qt, fires, key, _dlv, live, _pm = deliver(
-                    dags, qt, fires, key, t, qv, qkind, qsrc, qdst, islot,
-                    horizon, fire_cap, part_mask, part_t0, part_t1, drop,
-                    nbr_idx, nbr_valid,
-                )
-                return (dags, qt, qv, fires, key, sstate, live,
-                        jnp.zeros((n,), bool), jnp.zeros((n,), jnp.int32),
-                        jnp.full((), -1, jnp.int32))
-
-            def do_infer(op):
-                dags, qt, qv, fires, key, sstate = op
-                stale = gated_staleness(dags)
-                sstate, qt, qv, admitted, batch_now = infer_step(
-                    serve, sstate, t, qt, qv, qkind, qseq, infer_base,
-                    serve_base, stale,
-                )
-                s_now = jnp.max(jnp.where(admitted, stale, -1)).astype(
-                    jnp.int32
-                )
-                return (dags, qt, qv, fires, key, sstate,
-                        jnp.zeros((n, n), bool), admitted, batch_now, s_now)
-
-            (dags, qt, qv, fires, key, sstate, live, admitted, batch_now,
-             s_now) = jax.lax.cond(
-                knd == events_lib.KIND_INFER, do_infer, do_net,
-                (dags, qt, qv, fires, key, sstate),
-            )
-            if obs is not None:
-                kw = {}
-                if obs.hist is not None:
-                    # per-request histograms: the stale vector only weighs
-                    # in when a batch admitted (an INFER head, which left
-                    # dags untouched), so recomputing it post-cond reads
-                    # exactly what infer_step saw
-                    kw = dict(
-                        serve_stale_node=gated_staleness(dags),
-                        serve_arrived=sstate.arrivals - old_sstate.arrivals,
-                        serve_enq=(sstate.queued - old_sstate.queued
-                                   + batch_now),
-                        serve_queued=sstate.queued,
-                    )
-                metrics, ring = obs_lib.observe_round(
-                    obs, metrics, ring, t, old, dags, live_edges=live,
-                    serve_counts=sstate.served, serve_stale=s_now,
-                    infer_nodes=admitted, infer_arg=batch_now, **kw,
-                )
-                return (dags, qt, qv, fires, key, done + 1, sstate,
-                        metrics, ring)
-            return dags, qt, qv, fires, key, done + 1, sstate
-
-        init = (dags, qtime, qvalid, jnp.zeros_like(qseq), key,
-                jnp.int32(0), sstate) + tuple(obs_carry)
-        out = jax.lax.while_loop(cond, body, init)
-        res = {"dags": out[0], "qt": out[1], "qv": out[2], "key": out[4],
-               "done": out[5], "sstate": out[6]}
-        if obs is not None:
-            res["metrics"], res["ring"] = out[7], out[8]
-        return res
-
-    return jax.jit(advance)
-
-
-@functools.lru_cache(maxsize=None)
-def _advance_events_bank_serve_jit(impl: str, bank_impl,
-                                   serve: ServeConfig, obs=None,
-                                   faults=None, codec=None):
-    """Bank event advance with inference load (``serve`` effective).
-
-    Transport heads run the bank batch EXACTLY as the serve-free program
-    (shared delivery block, continuous budget accrual, drain re-arm;
-    faulted variants swap in the fault-aware chunk service with the same
-    spoof-key derivation); an INFER head computes the live availability
-    reduction (``chunk_dedup``) and serves against the GATED view — rows
-    whose chunks have not arrived count as missing, so staleness-at-serve
-    is physical. ``codec`` scales ``chunk_bytes`` to encoded wire size as
-    everywhere else. Returns a dict (dags / bstate [/ fstate] / last_srv /
-    key / qt / qv / done / sstate [/ metrics / ring]).
-    """
-    deliver = _deliver_fn(impl, faults)
-    if faults is not None:
-        from repro.net import faults as faults_lib
-        masks = faults_lib._role_masks(faults)
-    if obs is not None:
-        from repro import obs as obs_lib
-    f = 1 if faults is not None else 0
-
-    def advance(*all_args):
-        if faults is not None:
-            (dags, have, credit, sent, fstate0, last_srv, digest, qtime,
-             qvalid, qkind, qsrc, qdst, qseq, islot, key, horizon, limit,
-             fire_cap, part_mask, part_t0, part_t1, drop, nbr_idx,
-             nbr_valid, bw_bytes, chunk_bytes, sstate0, serve_base,
-             infer_base, *obs_carry) = all_args
-        else:
-            (dags, have, credit, sent, last_srv, digest, qtime, qvalid,
-             qkind, qsrc, qdst, qseq, islot, key, horizon, limit, fire_cap,
-             part_mask, part_t0, part_t1, drop, nbr_idx, nbr_valid,
-             bw_bytes, chunk_bytes, sstate0, serve_base, infer_base,
-             *obs_carry) = all_args
-        if codec is not None:
-            chunk_bytes = chunk_bytes * codec.wire_ratio()
-        n = dags.publisher.shape[0]
-
-        def cond(carry):
-            qt, qv, done = carry[4 + f], carry[5 + f], carry[7 + f]
-            return events_lib._queue_head_due(qt, qv, horizon) & (done < limit)
-
-        def body(carry):
-            it = list(carry)
-            dags, bstate = it[0], it[1]
-            if faults is not None:
-                fstate = it[2]
-            last_srv, key, qt, qv, fires, done, sstate = it[2 + f:9 + f]
-            if obs is not None:
-                metrics, ring = it[9 + f], it[10 + f]
-                old_dags, old_sent = dags, bstate.sent
-                old_have, old_sstate = bstate.have, sstate
-                if faults is not None:
-                    old_rej = fstate.rejects
-            idx, _found = event_pop(qt, qkind, qseq, qv)
-            t = qt[idx]
-            knd = qkind[idx]
-
-            def do_net(op):
-                if faults is not None:
-                    dags, bstate, fstate, last_srv, key, qt, qv, fires, \
-                        sstate = op
-                else:
-                    dags, bstate, last_srv, key, qt, qv, fires, sstate = op
-                batch = qv & (qt == t)
-                is_drn = qkind == events_lib.KIND_DRAIN
-                drain = events_lib._edge_mask(n, qdst, qsrc, batch & is_drn)
-
-                def _with_round(op2):
-                    return deliver(
-                        *op2, t, qv, qkind, qsrc, qdst, islot, horizon,
-                        fire_cap, part_mask, part_t0, part_t1, drop,
-                        nbr_idx, nbr_valid,
-                    )
-
-                def _no_round(op2):
-                    dags, qt, fires, key = op2
-                    off = jnp.zeros((n, n), bool)
-                    pm = events_lib._partition_mask(
-                        t, part_mask, part_t0, part_t1
-                    )
-                    return dags, qt, fires, key, off, off, pm
-
-                dags, qt, fires, key, deliver_m, live, pm = jax.lax.cond(
-                    jnp.any(batch & (qkind == events_lib.KIND_DELIVER)),
-                    _with_round, _no_round, (dags, qt, fires, key),
-                )
-                svc = live | (drain & pm)
-                sched = deliver_m | drain
-                accr = jnp.where(svc, (t - last_srv) * bw_bytes, 0.0)
-                if faults is not None:
-                    skey = jax.random.fold_in(
-                        jax.random.fold_in(key, faults_lib._SALT_SPOOF),
-                        done,
-                    )
-                    bstate2, fstate2, pending = (
-                        faults_lib._fault_chunk_service(
-                            dags, bstate, fstate, digest, svc, accr,
-                            chunk_bytes, skey, faults, masks, bank_impl,
-                        )
-                    )
-                else:
-                    sat = chunk_kernel.chunk_dedup(
-                        bstate.have, digest, impl=bank_impl
-                    )
-                    bstate2, pending = bank_lib.chunk_step(
-                        dags, bstate, digest, sat, sat, svc, accr,
-                        chunk_bytes, return_pending=True,
-                    )
-                last_srv = jnp.where(sched, t, last_srv)
-                # strict-progress clamp: see the serve-free drain re-arm in
-                # events.py — an f32 credit residue can round the completion
-                # instant back to t and livelock the advance
-                rate_b = jnp.maximum(bw_bytes, 1e-9)
-                t_next = jnp.nextafter(t, jnp.float32(jnp.inf))
-                e_next = jnp.maximum(
-                    t + (chunk_bytes - bstate2.credit) / rate_b, t_next
-                )[qdst, qsrc]
-                e_retry = jnp.maximum(
-                    t + chunk_bytes / rate_b, t_next
-                )[qdst, qsrc]
-                e_svc = svc[qdst, qsrc]
-                e_pend = pending[qdst, qsrc]
-                qv = jnp.where(is_drn & e_svc, e_pend, qv)
-                qt = jnp.where(is_drn & e_svc,
-                               jnp.where(e_pend, e_next, jnp.inf), qt)
-                qt = jnp.where(batch & is_drn & ~e_svc, e_retry, qt)
-                out = (dags, bstate2)
-                out = out + ((fstate2,) if faults is not None else ())
-                return out + (last_srv, key, qt, qv, fires, sstate, live,
-                              jnp.zeros((n,), bool),
-                              jnp.zeros((n,), jnp.int32),
-                              jnp.full((), -1, jnp.int32))
-
-            def do_infer(op):
-                if faults is not None:
-                    dags, bstate, fstate, last_srv, key, qt, qv, fires, \
-                        sstate = op
-                else:
-                    dags, bstate, last_srv, key, qt, qv, fires, sstate = op
-                sat = chunk_kernel.chunk_dedup(
-                    bstate.have, digest, impl=bank_impl
-                )
-                stale = gated_staleness(dags, sat)
-                sstate, qt, qv, admitted, batch_now = infer_step(
-                    serve, sstate, t, qt, qv, qkind, qseq, infer_base,
-                    serve_base, stale,
-                )
-                s_now = jnp.max(jnp.where(admitted, stale, -1)).astype(
-                    jnp.int32
-                )
-                out = (dags, bstate)
-                out = out + ((fstate,) if faults is not None else ())
-                return out + (last_srv, key, qt, qv, fires, sstate,
-                              jnp.zeros((n, n), bool), admitted, batch_now,
-                              s_now)
-
-            op = (dags, bstate)
-            op = op + ((fstate,) if faults is not None else ())
-            op = op + (last_srv, key, qt, qv, fires, sstate)
-            res = jax.lax.cond(
-                knd == events_lib.KIND_INFER, do_infer, do_net, op
-            )
-            dags, bstate = res[0], res[1]
-            if faults is not None:
-                fstate = res[2]
-            (last_srv, key, qt, qv, fires, sstate, live, admitted,
-             batch_now, s_now) = res[2 + f:]
-            new = (dags, bstate)
-            new = new + ((fstate,) if faults is not None else ())
-            new = new + (last_srv, key, qt, qv, fires, done + 1, sstate)
-            if obs is not None:
-                kw = {}
-                if faults is not None:
-                    kw = dict(rejects=fstate.rejects,
-                              rejects_delta=fstate.rejects - old_rej,
-                              quarantine_after=faults.quarantine_after)
-                if obs.hist is not None:
-                    # see the bankless variant: only INFER heads give the
-                    # stale vector weight, and they leave dags/bstate
-                    # untouched, so the post-cond recompute is what
-                    # infer_step gated on
-                    sat_h = chunk_kernel.chunk_dedup(
-                        bstate.have, digest, impl=bank_impl
-                    )
-                    kw.update(
-                        serve_stale_node=gated_staleness(dags, sat_h),
-                        serve_arrived=sstate.arrivals - old_sstate.arrivals,
-                        serve_enq=(sstate.queued - old_sstate.queued
-                                   + batch_now),
-                        serve_queued=sstate.queued,
-                    )
-                metrics, ring = obs_lib.observe_round(
-                    obs, metrics, ring, t, old_dags, dags, live_edges=live,
-                    bytes_delta=bstate.sent - old_sent, bstate=bstate,
-                    digest=digest, bank_impl=bank_impl, old_have=old_have,
-                    serve_counts=sstate.served, serve_stale=s_now,
-                    infer_nodes=admitted, infer_arg=batch_now, **kw,
-                )
-                new = new + (metrics, ring)
-            return new
-
-        init = (dags, bank_lib.BankState(have=have, credit=credit,
-                                         sent=sent))
-        init = init + ((fstate0,) if faults is not None else ())
-        init = init + (last_srv, key, qtime, qvalid, jnp.zeros_like(qseq),
-                       jnp.int32(0), sstate0) + tuple(obs_carry)
-        out = jax.lax.while_loop(cond, body, init)
-        res = {"dags": out[0], "bstate": out[1]}
-        if faults is not None:
-            res["fstate"] = out[2]
-        res["last_srv"], res["key"] = out[2 + f], out[3 + f]
-        res["qt"], res["qv"] = out[4 + f], out[5 + f]
-        res["done"], res["sstate"] = out[7 + f], out[8 + f]
-        if obs is not None:
-            res["metrics"], res["ring"] = out[9 + f], out[10 + f]
-        return res
-
-    return jax.jit(advance)
 
 
 # ---------------------------------------------------------------------------

@@ -109,7 +109,7 @@ def assert_nets_bitwise(a, b, msg=""):
 # ---------------------------------------------------------------------------
 
 
-def _run_arm(serve, impl, arm, seed=0):
+def _run_arm(serve, impl, arm, seed=0, obs_cfg=None):
     n = 6
     bank_cfg = (BankGossipConfig(chunks_per_slot=2)
                 if arm in ("bank", "bank_faults", "bank_partition") else None)
@@ -127,7 +127,7 @@ def _run_arm(serve, impl, arm, seed=0):
            else topo.full(n, link_latency=1.0))
     net = make_net(top, serve=serve, impl=impl, seed=seed,
                    bank_cfg=bank_cfg, faults_cfg=faults_cfg,
-                   partition=partition)
+                   partition=partition, obs_cfg=obs_cfg)
     for i in range(n):
         publish_on(net, i, 1 + i, 0.25 + 0.5 * i)
     net.advance(7.5)
@@ -255,19 +255,29 @@ def test_priced_drain_rearm_makes_strict_progress():
         assert int(rep["arrivals"][node]) == expect, f"node {node}"
 
 
-def test_serve_randomness_leaves_main_key_untouched():
+@pytest.mark.parametrize("obs", [None, "obs"])
+@pytest.mark.parametrize("arm", ["plain", "bank", "faults", "bank_faults"])
+def test_serve_randomness_leaves_main_key_untouched(arm, obs):
     """INFER batches never split the main PRNG key: the key trajectory of
-    a serving run equals the serve-free run over the same net events."""
-    n = 4
-    a = make_net(topo.full(n, link_latency=1.0), serve=None)
-    b = make_net(topo.full(n, link_latency=1.0),
-                 serve=ServeConfig(rate=3.0))
-    for net in (a, b):
-        for i in range(n):
-            publish_on(net, i, 1 + i, 0.5 * i)
-        net.advance(12.0)
+    a serving run equals the serve-free run over the same net events, in
+    every layer combination of the event advance, telemetry on or off."""
+    from repro.obs import ObsConfig
+
+    obs_cfg = ObsConfig() if obs else None
+    a = _run_arm(None, "fused", arm, obs_cfg=obs_cfg)
+    b = _run_arm(ServeConfig(rate=3.0), "fused", arm, obs_cfg=obs_cfg)
+    assert b.serve_report()["served_total"] > 0
     np.testing.assert_array_equal(np.asarray(a._key), np.asarray(b._key))
     assert_dags_equal(a.replicas.dags, b.replicas.dags, "serve-on vs off")
+    if a.bank_cfg is not None:
+        assert_nets_bitwise(a, b, f"{arm}: serve-on vs off")
+    if a._fstate is not None:
+        for name in a._fstate._fields:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(a._fstate, name)),
+                np.asarray(getattr(b._fstate, name)),
+                err_msg=f"{arm}: fault field {name}",
+            )
 
 
 @settings(max_examples=15, deadline=None)

@@ -205,3 +205,53 @@ def test_armed_mesh_advance_compiles_for_v5e_2x2(v5e_2x2, monkeypatch, bank):
           else gossip_lib._advance_jit("fused", mesh, obs_cfg))
     compiled = fn.lower(*structs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _event_advance_args(bank: bool):
+    """The arguments of one event advance of the paper deployment (the
+    cells' transport program), taken from a single-device network just
+    before its dispatch (nothing runs)."""
+    from repro.net import gossip as gossip_lib
+    from repro.net import topology as topo
+    from repro.net.bank import BankGossipConfig
+
+    net = gossip_lib.GossipNetwork(
+        dag_lib.empty_dag(CAP, 2, NODES + 1), bank=jnp.zeros((CAP, 8)),
+        top=topo.full(NODES),
+        cfg=gossip_lib.GossipConfig(sync_period=1.0, engine="events"),
+        bank_cfg=BankGossipConfig(chunks_per_slot=CHUNKS) if bank else None)
+    captured = []
+
+    def capture(label, fn, *args):
+        captured.append(args)
+        raise _Captured
+
+    net._dispatch = capture
+    with pytest.raises(_Captured):
+        net.advance(4.0)
+    return captured[0]
+
+
+@pytest.mark.parametrize("bank", [False, True],
+                         ids=["advance_events", "advance_events_bank"])
+def test_event_advance_compiles_for_v5e(v5e_2x2, monkeypatch, bank):
+    """The event engine's one loop body on one chip, every optional layer
+    off: the gossip winner and the event-queue pop reach the chip's
+    compiler as kernels."""
+    from repro.kernels import dispatch
+    from repro.net import events
+
+    args = _event_advance_args(bank)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)   # trace the TPU path
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+    structs = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        args)
+    fn = (events._advance_events_bank_jit("fused", None) if bank
+          else events._advance_events_jit("fused"))
+    fn.clear_cache()      # a CPU trace at these shapes would be reused
+    try:
+        text = fn.lower(*structs).compile().as_text()
+    finally:
+        fn.clear_cache()
+    assert text.count("tpu_custom_call") >= 2, "winner and pop kernels"
